@@ -90,7 +90,9 @@ def _serve_once(name: str, extra: dict) -> dict:
     runtime = "pingpong" if name.startswith("pingpong") else name
     kw = {**WORKLOAD, **extra}      # entries may override workload knobs
     try:
-        return serve_run("mixtral-8x22b", runtime=runtime, **kw)
+        stats = serve_run("mixtral-8x22b", runtime=runtime, **kw)
+        del stats["engine"]     # nothing here reads it; let it be freed
+        return stats
     finally:
         # every run builds a fresh engine/runtime (per-instance jits;
         # warmup_requests absorbs the recompile before timing), so

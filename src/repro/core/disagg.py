@@ -44,6 +44,7 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import ModelConfig
@@ -73,11 +74,31 @@ def _layer_index(cfg: ModelConfig, l: int):
     return ("remainder", l - scanned, None)
 
 
+@jax.jit
+def _take_layer(tree, i):
+    """Layer ``i`` of a stacked parameter tree, in one program (eager
+    indexing materializes each leaf's slice before dropping its leading
+    axis, a transient of a whole leaf)."""
+    return jax.tree.map(lambda a: a[i], tree)
+
+
 def _slice_layer_params(params: dict, cfg: ModelConfig, l: int) -> dict:
     where, pos, blk = _layer_index(cfg, l)
     if where == "block":
-        return jax.tree.map(lambda a: a[blk], params["blocks"][pos])
+        return _take_layer(params["blocks"][pos], blk)
     return params["remainder"][pos]
+
+
+def per_device(mesh: Mesh, spec, fn):
+    """``fn`` run per device of ``mesh`` under ``shard_map``, every
+    argument and result laid out by ``spec``.  XLA cannot partition a
+    Mosaic kernel across devices, so a stage that calls one on a
+    multi-device mesh is handed to each device whole; on one device
+    this is ``fn`` itself."""
+    if mesh.size == 1:
+        return fn
+    return shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                     check_vma=False)
 
 
 def _layer_kind(cfg: ModelConfig, l: int) -> str:
@@ -90,8 +111,8 @@ def _layer_kind(cfg: ModelConfig, l: int) -> str:
 class DisaggPlan:
     n_microbatches: int = 3
     capacity_mode: str = "full"
-    # route the expert GEMMs through the Pallas grouped_matmul kernel
-    # (interpret mode on CPU; real kernel on TPU) — §6 "fused kernels"
+    # route the hot path through the Pallas kernels (compiled on TPU,
+    # interpret mode elsewhere) — §6 "fused kernels"
     use_kernels: bool = False
     # route MoE layers through the shard_map M2N dispatch (repro.core.m2n):
     # routing is computed per expert shard, only locally-owned tokens are
@@ -152,22 +173,18 @@ class DisaggregatedInstance:
             return {k: v for k, v in tree.items() if k not in EXPERT_KEYS}
 
         self.layers_attn: List[dict] = []
-        self.layers_expert: List[Optional[dict]] = []
-        # un-placed expert weights, kept to regather on live rebalances
-        # (apply_placement) — the §6 replication path needs the global
-        # (E, ...) arrays to build per-node virtual-slot copies from
-        self._moe_raw: List[Optional[dict]] = []
+        self.layers_expert: List[dict] = []
+        routers: List[dict] = []
         for l in range(cfg.n_layers):
             lp = _slice_layer_params(params, cfg, l)
             self.layers_attn.append(attn_side(lp))
             if cfg.moe is not None:
-                le = {k: lp[k] for k in EXPERT_KEYS}
-                self.layers_expert.append(le)
-                self._moe_raw.append(le)
+                self.layers_expert.append({k: lp[k] for k in EXPERT_KEYS})
+                routers.append({k: lp[k] for k in ("router", "router_bias")
+                                if k in lp})
             else:
                 self.layers_expert.append(
                     {"w1": lp["w1"], "w3": lp["w3"], "w2": lp["w2"]})
-                self._moe_raw.append(None)
         self.head = {k: params[k] for k in ("embed", "final_norm", "lm_head")
                      if k in params}
 
@@ -183,6 +200,8 @@ class DisaggregatedInstance:
                         "w3": NamedSharding(self.expert_mesh, P(None, "ep")),
                         "w2": NamedSharding(self.expert_mesh, P("ep", None))}
             self.expert_in_spec = P()           # (T, d) replicated (TP FFN)
+        # the un-placed (E, ...) expert weights: live rebalances
+        # (apply_placement) regather per-node virtual-slot copies from them
         self.layers_expert = [
             jax.device_put(le, ep_shard) for le in self.layers_expert]
         # the M2N path computes routing on the expert shards (replicated
@@ -191,14 +210,8 @@ class DisaggregatedInstance:
         self.layers_router_ep: List[Optional[dict]] = [None] * cfg.n_layers
         if cfg.moe is not None and plan.use_m2n:
             rep_e = NamedSharding(self.expert_mesh, P())
-            routers = []
-            for l in range(cfg.n_layers):
-                lp = _slice_layer_params(params, cfg, l)
-                rp = {"router": lp["router"]}
-                if "router_bias" in lp:
-                    rp["router_bias"] = lp["router_bias"]
-                routers.append(jax.device_put(rp, rep_e))
-            self.layers_router_ep = routers
+            self.layers_router_ep = [jax.device_put(rp, rep_e)
+                                     for rp in routers]
 
         # ---- live expert placement (§6) ----------------------------------
         # placement starts out static (contiguous expert blocks); the
@@ -221,6 +234,9 @@ class DisaggregatedInstance:
         self.reset_stage_times()
         self.reset_expert_counts()
         self.last_trace: List[tuple] = []
+        # stage -> (jitted program, args) of its latest call; read only
+        # by compiled_stages()
+        self._last_calls: dict = {}
         self._build_jits()
 
     @property
@@ -292,8 +308,10 @@ class DisaggregatedInstance:
         def expert_phase_moe(pe, xe):
             if self.plan.use_kernels:
                 from repro.kernels import ops as kops
-                return kops.grouped_mlp(xe, pe["we1"], pe["we3"], pe["we2"],
-                                        cfg.act)
+                return per_device(
+                    self.expert_mesh, P("ep"),
+                    lambda pe, xe: kops.grouped_mlp(
+                        xe, pe["we1"], pe["we3"], pe["we2"], cfg.act))(pe, xe)
             h = moe_lib.activation(jnp.einsum("ecd,edf->ecf", xe, pe["we1"]),
                                    cfg.act)
             h = h * jnp.einsum("ecd,edf->ecf", xe, pe["we3"])
@@ -348,16 +366,25 @@ class DisaggregatedInstance:
         def lm_head(head, x):
             return _lm_head(head, cfg, x)
 
-        self._attn_phase = {
-            w: jax.jit(lambda p, x, a, c, pos, w=w:
-                       attn_phase(p, x, a, c, pos, w))
-            for w in {0, cfg.window}}
+        def attn_program(window, placed):
+            if placed:
+                fn = (lambda p, tbl, x, a, c, pos:
+                      attn_phase(p, x, a, c, pos, window, tbl))
+            else:
+                fn = (lambda p, x, a, c, pos:
+                      attn_phase(p, x, a, c, pos, window))
+            if self.plan.use_kernels:
+                # the attention group computes replicated; its kernels
+                # run once per device
+                fn = per_device(self.attn_mesh, P(), fn)
+            return jax.jit(fn)
+
+        self._attn_phase = {w: attn_program(w, False)
+                            for w in {0, cfg.window}}
         # placed variants thread the placement lookup tables through the
         # dispatch; traced lazily on the first rebalanced decode step
-        self._attn_phase_placed = {
-            w: jax.jit(lambda p, tbl, x, a, c, pos, w=w:
-                       attn_phase(p, x, a, c, pos, w, tbl))
-            for w in {0, cfg.window}}
+        self._attn_phase_placed = {w: attn_program(w, True)
+                                   for w in {0, cfg.window}}
         ein = NamedSharding(self.expert_mesh, self.expert_in_spec)
         if cfg.moe is not None and self.plan.use_m2n:
             # tokens arrive replicated on the expert mesh; the shard_map
@@ -456,7 +483,7 @@ class DisaggregatedInstance:
         # per-hop bytes/latency land under the "weights" kind
         self.layers_expert_placed = self.transport.regather_weights(
             [{k: raw[k][gather] for k in EXPERT_KEYS}
-             for raw in self._moe_raw],
+             for raw in self.layers_expert],
             ep_shard).data
         tbl = {"rep_node": jnp.asarray(tables.rep_node),
                "rep_slot": jnp.asarray(tables.rep_slot),
@@ -547,7 +574,16 @@ class DisaggregatedInstance:
             jax.block_until_ready(out)
         self.stage_times[stage] += time.perf_counter() - t0
         self.stage_counts[stage] += 1
+        if hasattr(fn, "lower"):
+            self._last_calls[stage] = (fn, args)
         return out
+
+    def compiled_stages(self) -> dict:
+        """Optimized HLO text of each jitted stage program, compiled for
+        the arguments of its latest call — the programs the last decode
+        step ran (the M2N/N2M hops are transport puts, not programs)."""
+        return {stage: fn.lower(*args).compile().as_text()
+                for stage, (fn, args) in self._last_calls.items()}
 
     def stage_report(self) -> dict:
         """Cumulative per-stage seconds/counts plus the paper's per-op
@@ -570,7 +606,8 @@ class DisaggregatedInstance:
         ``stage_report()`` with device-accurate stage times."""
         tokens = jnp.zeros((batch,), jnp.int32)
         pos = jnp.zeros((batch,), jnp.int32)
-        cache = init_cache(self.cfg, batch, max_seq, jnp.float32)
+        cache = init_cache(self.cfg, batch, max_seq,
+                           self.head["embed"].dtype)
         prev = self.plan.profile_stages
         self.plan.profile_stages = True
         try:

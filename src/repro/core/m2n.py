@@ -29,13 +29,8 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-try:  # jax >= 0.6: top-level export, replication check kw is check_vma
-    from jax import shard_map
-    _SHARD_MAP_KWARGS = {"check_vma": False}
-except ImportError:  # jax 0.4.x: experimental location, kw is check_rep
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_KWARGS = {"check_rep": False}
 
 from repro.config import MoEConfig
 from repro.models import moe as moe_lib
@@ -223,7 +218,7 @@ def sharded_routed_experts(params: dict, x: jax.Array, cfg: MoEConfig,
         in_specs=(P(dtuple, None), P(None, None), P(None), P(dtuple))
         + w_specs + tbl_specs,
         out_specs=out_specs,
-        **_SHARD_MAP_KWARGS,
+        check_vma=False,
     )
     if transport is not None and n_shards > 1:
         itemsize = jnp.dtype(x.dtype).itemsize

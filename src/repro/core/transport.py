@@ -259,11 +259,7 @@ def _distributed_initialized() -> bool:
     the distributed client state, NOT ``jax.process_count()``: touching
     the backend before initialize would lock JAX into single-process
     mode ("must be called before any JAX computations")."""
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if callable(is_init):
-        return bool(is_init())
-    from jax._src import distributed as _dist
-    return getattr(_dist.global_state, "client", None) is not None
+    return jax.distributed.is_initialized()
 
 
 def _env_int(*names: str) -> Optional[int]:
@@ -316,11 +312,8 @@ class MultiControllerTransport(Transport):
             # gloo makes multi-process computations work on the CPU
             # backend (the default errors with "Multiprocess computations
             # aren't implemented"); must be set before initialize()
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  cpu_collectives)
-            except (AttributeError, ValueError):  # older jaxlib: n/a
-                pass
+            jax.config.update("jax_cpu_collectives_implementation",
+                              cpu_collectives)
             jax.distributed.initialize(
                 coordinator_address=self.spec.coordinator,
                 num_processes=self.spec.num_processes,

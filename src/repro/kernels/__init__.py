@@ -1,8 +1,8 @@
 """Pallas TPU kernels for the serving hot path (paper §6 "fused
-kernels"), validated in interpret mode on CPU against the pure-jnp
-oracles in ``repro.kernels.ref``.
+kernels"): compiled with Mosaic on TPU, and validated in interpret mode
+on CPU against the pure-jnp oracles in ``repro.kernels.ref``.
 
-The public API is the jit'd ``ops`` wrappers re-exported here — callers
+The public API is the jit'd ``ops`` entry points re-exported here — callers
 use ``from repro.kernels import grouped_mlp`` (or ``ops.grouped_mlp``)
 rather than deep-importing the per-kernel modules.
 """

@@ -5,61 +5,102 @@ attends over its (ring-buffer) KV cache.  This is memory-bound — the
 kernel's job is to stream the KV cache HBM->VMEM exactly once per step
 with an online-softmax accumulator resident in VMEM.
 
-Layout: q (B, Hkv, rep, hd); k/v cache (B, W, Hkv, hd); grid
-(B, Hkv, W/Wb) with the KV-length dimension innermost so the
-(rep, hd) f32 accumulator and the (rep,) running max/denominator stay in
-scratch across KV blocks.
+Layout: q (B, Hkv, rep, hd); k/v cache (B, W, Hkv, hd), read in
+(Wb, Hkv, hd) blocks that hold every kv-head — the block's last two dims
+are the cache's own (Hkv, hd), which Mosaic accepts and which match the
+cache's HBM tiling, so no relayout copy of the cache is made.  Grid
+(B, W/Wb) with the KV-length dimension innermost; the kernel walks the
+kv-heads of a block in a static loop, each with its (rep, hd) f32
+accumulator and (rep, 1) running max/denominator kept in scratch across
+KV blocks.  Each request's decode position rides in SMEM as a
+scalar-prefetch argument.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_mode
 
-def _kernel(q_ref, k_ref, v_ref, cpos_ref, pos_ref, o_ref,
-            acc_ref, m_ref, l_ref, *, nw: int, window: int,
-            attn_softcap: float, scale: float):
-    w_step = pl.program_id(2)
 
+def _attend_block(q_ref, k_ref, v_ref, ok, o_ref, acc_ref, m_ref, l_ref, *,
+                  w_step, nw: int, attn_softcap: float, scale: float):
+    """One (Wb, Hkv, hd) KV block of the online softmax, every kv-head.
+    ``ok``: (1, Wb) mask of the slots this query may attend to."""
     @pl.when(w_step == 0)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, -1e30)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                    # (rep, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)                 # (Wb, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if attn_softcap > 0.0:
-        s = attn_softcap * jnp.tanh(s / attn_softcap)
-    cpos = cpos_ref[0]                                     # (Wb,)
-    pos = pos_ref[0]
-    ok = (cpos >= 0) & (cpos <= pos)
-    if window > 0:
-        ok &= cpos > (pos - window)
-    s = jnp.where(ok[None, :], s, -1e30)
+    okf = ok.astype(jnp.float32)
+    for g in range(q_ref.shape[0]):
+        q = q_ref[g].astype(jnp.float32)                   # (rep, hd)
+        k = k_ref[:, g, :].astype(jnp.float32)             # (Wb, hd)
+        v = v_ref[:, g, :].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if attn_softcap > 0.0:
+            s = attn_softcap * jnp.tanh(s / attn_softcap)
+        s = jnp.where(ok, s, -1e30)
 
-    m_old = m_ref[:, 0]
-    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_old - m_new)
-    p = jnp.exp(s - m_new[:, None]) * ok[None, :].astype(jnp.float32)
-    l_new = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                    + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32))
-    m_ref[:, 0] = m_new
-    l_ref[:, 0] = l_new
+        m_old = m_ref[g]                                   # (rep, 1)
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_old - m_new)
+        p = jnp.exp(s - m_new) * okf
+        l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[g] = (acc_ref[g] * alpha
+                      + jax.lax.dot_general(
+                          p, v, (((1,), (0,)), ((), ())),
+                          preferred_element_type=jnp.float32))
+        m_ref[g] = m_new
 
     @pl.when(w_step == nw - 1)
     def _():
-        denom = jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def _valid(cpos, pos, window: int):
+    ok = (cpos >= 0) & (cpos <= pos)
+    if window > 0:
+        ok &= cpos > (pos - window)
+    return ok
+
+
+def _kernel(pos_ref, q_ref, k_ref, v_ref, cpos_ref, o_ref,
+            acc_ref, m_ref, l_ref, *, nw: int, window: int,
+            attn_softcap: float, scale: float):
+    ok = _valid(cpos_ref[...], pos_ref[pl.program_id(0)], window)
+    _attend_block(q_ref, k_ref, v_ref, ok, o_ref, acc_ref, m_ref, l_ref,
+                  w_step=pl.program_id(1), nw=nw, attn_softcap=attn_softcap,
+                  scale=scale)
+
+
+def _kv_block(W: int, wb: int) -> int:
+    """KV block length: ``wb`` halved until it divides W, no lower than
+    128 (the lane width the (1, Wb) position block needs); all of W when
+    no such block divides it."""
+    while W % wb and wb > 128:
+        wb //= 2
+    return wb if W % wb == 0 else W
+
+
+def _specs(Hkv: int, rep: int, hd: int, kv_rows: int, kv_index, q_index):
+    """Block specs shared by both kernels: q / out (Hkv, rep, hd) per
+    request, k / v (kv_rows, Hkv, hd) per KV block, and the f32
+    accumulator + running max / denominator scratch."""
+    q_spec = pl.BlockSpec((None, Hkv, rep, hd), q_index)
+    kv_spec = pl.BlockSpec((None, kv_rows, Hkv, hd), kv_index)
+    scratch = [pltpu.VMEM((Hkv, rep, hd), jnp.float32),
+               pltpu.VMEM((Hkv, rep, 1), jnp.float32),
+               pltpu.VMEM((Hkv, rep, 1), jnp.float32)]
+    return q_spec, kv_spec, scratch
 
 
 @functools.partial(jax.jit,
@@ -67,88 +108,54 @@ def _kernel(q_ref, k_ref, v_ref, cpos_ref, pos_ref, o_ref,
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      cache_pos: jax.Array, pos: jax.Array, *,
                      window: int = 0, attn_softcap: float = 0.0,
-                     wb: int = 512, interpret: bool = True) -> jax.Array:
+                     wb: int = 512,
+                     interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, H, hd); caches (B, W, Hkv, hd); cache_pos (B, W); pos (B,).
 
-    Returns (B, H, hd).  VMEM per step: 2*Wb*hd (k,v) + rep*hd acc —
-    with Wb=512, hd=128: ~0.6 MB, so the 524k-long cache streams through
-    in 1024 sequential blocks per (batch, kv-head).
+    Returns (B, H, hd).  VMEM per step: 2*Wb*Hkv*hd (k,v) + H*hd acc —
+    with Wb=512, Hkv=8, hd=128 in bf16: ~2 MB, so the 524k-long cache
+    streams through in 1024 sequential blocks per request.
     """
     B, H, hd = q.shape
     _, W, Hkv, _ = k_cache.shape
     rep = H // Hkv
-    while W % wb:
-        wb //= 2
-    wb = max(wb, 1)
-    qg = q.reshape(B, Hkv, rep, hd)
-    grid = (B, Hkv, W // wb)
-    out = pl.pallas_call(
-        functools.partial(_kernel, nw=grid[2], window=window,
-                          attn_softcap=attn_softcap, scale=hd ** -0.5),
+    wb = _kv_block(W, wb)
+    grid = (B, W // wb)
+    q_spec, kv_spec, scratch = _specs(
+        Hkv, rep, hd, wb, kv_index=lambda b, w, p: (b, w, 0, 0),
+        q_index=lambda b, w, p: (b, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, hd), lambda b, g, w: (b, g, 0, 0)),
-            pl.BlockSpec((1, wb, 1, hd), lambda b, g, w: (b, w, g, 0)),
-            pl.BlockSpec((1, wb, 1, hd), lambda b, g, w: (b, w, g, 0)),
-            pl.BlockSpec((1, wb), lambda b, g, w: (b, w)),
-            pl.BlockSpec((1,), lambda b, g, w: (b,)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, hd), lambda b, g, w: (b, g, 0, 0)),
+        in_specs=[q_spec, kv_spec, kv_spec,
+                  pl.BlockSpec((None, 1, wb), lambda b, w, p: (b, 0, w))],
+        out_specs=q_spec,
+        scratch_shapes=scratch,
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, nw=grid[1], window=window,
+                          attn_softcap=attn_softcap, scale=hd ** -0.5),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((rep, hd), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qg, k_cache, v_cache, cache_pos, pos)
+        interpret=interpret_mode(interpret),
+        name="decode_attention",
+    )(pos.astype(jnp.int32), q.reshape(B, Hkv, rep, hd), k_cache, v_cache,
+      cache_pos.reshape(B, 1, W))
     return out.reshape(B, H, hd)
 
 
-def _paged_kernel(bt_ref, q_ref, k_ref, v_ref, ppos_ref, pos_ref, o_ref,
+def _paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, ppos_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, nw: int, window: int,
                   attn_softcap: float, scale: float):
     b = pl.program_id(0)
-    w_step = pl.program_id(2)
-
-    @pl.when(w_step == 0)
-    def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)                    # (rep, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)                 # (ps, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if attn_softcap > 0.0:
-        s = attn_softcap * jnp.tanh(s / attn_softcap)
-    cpos = ppos_ref[0]                                     # (ps,)
-    pos = pos_ref[0]
+    w_step = pl.program_id(1)
     # an unmapped logical page (-1 in the block table) was DMA'd from
     # clipped page 0 — mask the whole block so its garbage never scores
     mapped = bt_ref[b, w_step] >= 0
-    ok = mapped & (cpos >= 0) & (cpos <= pos)
-    if window > 0:
-        ok &= cpos > (pos - window)
-    s = jnp.where(ok[None, :], s, -1e30)
-
-    m_old = m_ref[:, 0]
-    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_old - m_new)
-    p = jnp.exp(s - m_new[:, None]) * ok[None, :].astype(jnp.float32)
-    l_new = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                    + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32))
-    m_ref[:, 0] = m_new
-    l_ref[:, 0] = l_new
-
-    @pl.when(w_step == nw - 1)
-    def _():
-        denom = jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+    ok = mapped & _valid(ppos_ref[...], pos_ref[b], window)
+    _attend_block(q_ref, k_ref, v_ref, ok, o_ref, acc_ref, m_ref, l_ref,
+                  w_step=w_step, nw=nw, attn_softcap=attn_softcap,
+                  scale=scale)
 
 
 @functools.partial(jax.jit,
@@ -157,7 +164,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, pos_pages: jax.Array,
                            block_table: jax.Array, pos: jax.Array, *,
                            window: int = 0, attn_softcap: float = 0.0,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None) -> jax.Array:
     """Block-table-indexed flash decode over a paged KV pool.
 
     q: (B, H, hd); k_pages/v_pages: (P, ps, Hkv, hd); pos_pages: (P, ps);
@@ -169,48 +176,43 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     address is *computed from the table* in the BlockSpec index_map —
     the kernel streams exactly the pages a request owns straight out of
     the shared pool, with no dense gather materialized in HBM.  Grid is
-    (B, Hkv, n_logical) with the page dimension innermost, same online
-    softmax as the contiguous kernel; unmapped pages (clipped to page 0
-    for the DMA) are masked out in-kernel via the prefetched table.
+    (B, n_logical) with the page dimension innermost, same online
+    softmax and all-kv-head (ps, Hkv, hd) blocks as the contiguous
+    kernel; unmapped pages (clipped to page 0 for the DMA) are masked out
+    in-kernel via the prefetched table.
     """
     B, H, hd = q.shape
     P, ps, Hkv, _ = k_pages.shape
     n_logical = block_table.shape[1]
     rep = H // Hkv
-    qg = q.reshape(B, Hkv, rep, hd)
     bt = jnp.asarray(block_table, jnp.int32)
-    grid = (B, Hkv, n_logical)
+    grid = (B, n_logical)
 
     def page_of(b, w, bt):
         # unmapped (-1) entries DMA page 0; the kernel masks them via
         # the same prefetched (unclipped) table
         return jnp.maximum(bt[b, w], 0)
 
+    q_spec, kv_spec, scratch = _specs(
+        Hkv, rep, hd, ps,
+        kv_index=lambda b, w, bt, p: (page_of(b, w, bt), 0, 0, 0),
+        q_index=lambda b, w, bt, p: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, hd), lambda b, g, w, bt: (b, g, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, g, w, bt: (page_of(b, w, bt), 0, g, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, g, w, bt: (page_of(b, w, bt), 0, g, 0)),
-            pl.BlockSpec((1, ps), lambda b, g, w, bt: (page_of(b, w, bt), 0)),
-            pl.BlockSpec((1,), lambda b, g, w, bt: (b,)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, hd),
-                               lambda b, g, w, bt: (b, g, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep, hd), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec,
+                  pl.BlockSpec((None, 1, ps),
+                               lambda b, w, bt, p: (page_of(b, w, bt), 0, 0))],
+        out_specs=q_spec,
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, nw=n_logical, window=window,
                           attn_softcap=attn_softcap, scale=hd ** -0.5),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hd), q.dtype),
-        interpret=interpret,
-    )(bt, qg, k_pages, v_pages, pos_pages, pos)
+        interpret=interpret_mode(interpret),
+        name="paged_decode_attention",
+    )(bt, pos.astype(jnp.int32), q.reshape(B, Hkv, rep, hd), k_pages,
+      v_pages, pos_pages.reshape(P, 1, ps))
     return out.reshape(B, H, hd)

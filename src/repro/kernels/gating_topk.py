@@ -18,8 +18,9 @@ against live placement tables (``models.moe.replica_assign`` semantics,
 token-index hash recomputed in-kernel), shard-ownership filter, and
 capacity-slot positions.  Slot order is exactly
 ``models.moe.dispatch_indices``'s token-major first-come-first-served
-order: within a block via a flattened one-hot cumsum, across blocks via
-a VMEM scratch of running per-bucket occupancy (the grid is sequential,
+order: within a block via a strictly-lower-triangular 0/1 matmul over
+per-token bucket one-hots (Mosaic has no cumsum), across blocks via a
+VMEM scratch of running per-bucket occupancy (the grid is sequential,
 so block i+1 sees the totals of blocks 0..i).  The (n_buckets, C)
 index/gate buffer scatter stays in the jnp wrapper — TPU kernels avoid
 in-kernel scatters; the fusion win is eliminating the memory-bound
@@ -28,11 +29,14 @@ in-kernel scatters; the fusion win is eliminating the memory-bound
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import interpret_mode
 
 
 def _topk_core(x, w, top_k: int, bias=None):
@@ -68,13 +72,13 @@ def _kernel(x_ref, w_ref, gates_ref, idx_ref, counts_ref, *, top_k: int):
     E = w.shape[-1]
     gates_ref[...] = gates
     idx_ref[...] = idx
-    onehot = jax.nn.one_hot(idx, E, dtype=jnp.int32)      # (Tb, K, E)
-    counts_ref[...] = jnp.sum(onehot, axis=(0, 1))[None]
+    counts = sum(_one_hot(idx[:, k:k + 1], E) for k in range(top_k))
+    counts_ref[...] = jnp.sum(counts, axis=0, keepdims=True).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("top_k", "tb", "interpret"))
 def gating_topk(x: jax.Array, w_router: jax.Array, top_k: int, *,
-                tb: int = 256, interpret: bool = True):
+                tb: int = 256, interpret: Optional[bool] = None):
     """x: (T, d), w_router: (d, E) -> (gates (T,K), experts (T,K), counts (E,)).
 
     VMEM per step: Tb*d (x) + d*E (router) + Tb*E (logits) — for
@@ -82,9 +86,7 @@ def gating_topk(x: jax.Array, w_router: jax.Array, top_k: int, *,
     """
     T, d = x.shape
     E = w_router.shape[1]
-    while T % tb:
-        tb //= 2
-    tb = max(tb, 1)
+    tb = _token_block(T, tb)
     grid = (T // tb,)
     gates, idx, counts = pl.pallas_call(
         functools.partial(_kernel, top_k=top_k),
@@ -96,37 +98,57 @@ def gating_topk(x: jax.Array, w_router: jax.Array, top_k: int, *,
         out_specs=[
             pl.BlockSpec((tb, top_k), lambda i: (i, 0)),
             pl.BlockSpec((tb, top_k), lambda i: (i, 0)),
-            pl.BlockSpec((1, E), lambda i: (i, 0)),
+            pl.BlockSpec((None, 1, E), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((T, top_k), jnp.float32),
             jax.ShapeDtypeStruct((T, top_k), jnp.int32),
-            jax.ShapeDtypeStruct((grid[0], E), jnp.int32),
+            jax.ShapeDtypeStruct((grid[0], 1, E), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="gating_topk",
     )(x, w_router)
-    return gates, idx, jnp.sum(counts, axis=0)
+    return gates, idx, jnp.sum(counts, axis=(0, 1))
 
 
 def _hash01(tok):
     """In-kernel twin of ``models.moe._token_hash01`` (splitmix-style):
-    token index -> [0, 1) f32.  Must stay bit-identical so the kernel's
-    replica choice matches the jnp path's."""
-    h = tok.astype(jnp.uint32) * jnp.uint32(2654435761)
-    h = h ^ (h >> 16)
-    h = h * jnp.uint32(2246822519)
-    h = h ^ (h >> 13)
-    return h.astype(jnp.float32) * jnp.float32(2.0 ** -32)
+    token index (int32) -> [0, 1) f32, bit-identical to the jnp path.
+    Written in int32 arithmetic with logical shifts (the same bit
+    patterns as its uint32 form), and the final uint32 -> f32 conversion
+    done as hi * 2^16 + lo: both halves convert exactly, so the one
+    rounding of the f32 add equals the rounding of the direct
+    conversion."""
+    srl = jax.lax.shift_right_logical
+    h = tok * jnp.int32(-1640531535)                  # 2654435761 as int32
+    h = h ^ srl(h, jnp.int32(16))
+    h = h * jnp.int32(-2048144777)                    # 2246822519 as int32
+    h = h ^ srl(h, jnp.int32(13))
+    hi = srl(h, jnp.int32(16)).astype(jnp.float32)
+    lo = (h & jnp.int32(0xFFFF)).astype(jnp.float32)
+    return (hi * jnp.float32(65536.0) + lo) * jnp.float32(2.0 ** -32)
+
+
+def _one_hot(col, n: int):
+    """(Tb, 1) int32 -> (Tb, n) f32 one-hot, by iota compare."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (col.shape[0], n), 1)
+    return (col == iota).astype(jnp.float32)
+
+
+def _dot(a, b, precision=None):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
 
 
 def _dispatch_kernel(*refs, top_k: int, n_buckets: int, slots_per_node: int,
                      tb: int, use_tables: bool):
     if use_tables:
-        (x_ref, w_ref, b_ref, cw_ref, own_ref, rn_ref, rs_ref, rc_ref,
+        (own_ref, x_ref, w_ref, b_ref, cw_ref, rn_ref, rs_ref, rc_ref,
          gates_ref, bucket_ref, pos_ref, valid_ref, counts_ref,
          base_ref) = refs
     else:
-        (x_ref, w_ref, b_ref, cw_ref, own_ref,
+        (own_ref, x_ref, w_ref, b_ref, cw_ref,
          gates_ref, bucket_ref, pos_ref, valid_ref, counts_ref,
          base_ref) = refs
     i = pl.program_id(0)
@@ -142,51 +164,73 @@ def _dispatch_kernel(*refs, top_k: int, n_buckets: int, slots_per_node: int,
     gates, experts = _topk_core(x, w, top_k, bias=b_ref[...])
     gates_ref[...] = gates
     E = w.shape[-1]
-    oh_e = jax.nn.one_hot(experts, E, dtype=jnp.float32)     # (Tb, K, E)
-    # per-original-expert weighted counts (the live traffic trace) —
-    # computed before any replica split, like the jnp path
+    own = own_ref[0]
     cw = cw_ref[...]                                          # (Tb, 1)
-    counts_ref[...] = jnp.sum(oh_e * cw[:, :, None], axis=(0, 1))[None]
-
     if use_tables:
         # replica_assign: hash the *global* token index against the
         # replica cumulative-traffic fractions; all (E,R) table lookups
-        # are one_hot matmuls (no dynamic gather on TPU)
+        # are exact one_hot matmuls (no dynamic gather on TPU)
         R = rc_ref.shape[-1]
         tok = i * tb + jax.lax.broadcasted_iota(jnp.int32, (tb, 1), 0)
         u = _hash01(tok)                                      # (Tb, 1)
-        flat_e = oh_e.reshape(tb * top_k, E)
-        take = lambda t_ref: jax.lax.dot_general(
-            flat_e, t_ref[...].astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).reshape(tb, top_k, R)
-        cum = take(rc_ref)                                    # (Tb, K, R)
-        r = jnp.sum(u[:, :, None] >= cum, axis=-1).astype(jnp.int32)
-        r = jnp.minimum(r, R - 1)
-        oh_r = jax.nn.one_hot(r, R, dtype=jnp.float32)        # (Tb, K, R)
-        node = jnp.sum(take(rn_ref) * oh_r, -1).astype(jnp.int32)
-        slot = jnp.sum(take(rs_ref) * oh_r, -1).astype(jnp.int32)
-        vslot = node * slots_per_node + slot
-    else:
-        vslot = experts
-        node = vslot // slots_per_node
-    own = own_ref[0, 0]
-    valid = (own < 0) | (node == own)                         # (Tb, K)
-    bucket_ref[...] = vslot
-    valid_ref[...] = valid.astype(jnp.int32)
+        hi = jax.lax.Precision.HIGHEST
+        tables = [t_ref[...].astype(jnp.float32)
+                  for t_ref in (rn_ref, rs_ref, rc_ref)]
 
-    # capacity-slot positions, token-major within the block (exactly
-    # dispatch_indices' flattened cumsum order), only valid entries
-    # occupy a slot
-    oh_b = (jax.nn.one_hot(vslot, n_buckets, dtype=jnp.float32)
-            * valid[..., None].astype(jnp.float32))           # (Tb, K, B)
-    flat = oh_b.reshape(tb * top_k, n_buckets)
-    run = jnp.cumsum(flat, axis=0) - flat
-    pos_in = jnp.sum(run.reshape(tb, top_k, n_buckets) * oh_b, axis=-1)
-    base = base_ref[...]                                      # (1, B) f32
-    pos = pos_in + jnp.sum(oh_b * base[0][None, None, :], axis=-1)
-    pos_ref[...] = pos.astype(jnp.int32)
-    base_ref[...] = base + jnp.sum(flat, axis=0)[None]
+    counts = jnp.zeros((1, E), jnp.float32)
+    ohs = []
+    for k in range(top_k):
+        e_k = experts[:, k:k + 1]                             # (Tb, 1)
+        oh_e = _one_hot(e_k, E)                               # (Tb, E)
+        # per-original-expert weighted counts (the live traffic trace)
+        # — computed before any replica split, like the jnp path
+        counts = counts + jnp.sum(oh_e * cw, axis=0, keepdims=True)
+        if use_tables:
+            rn, rs, rc = (_dot(oh_e, t, hi) for t in tables)  # (Tb, R)
+            r = jnp.sum((u >= rc).astype(jnp.int32), axis=-1, keepdims=True)
+            oh_r = _one_hot(jnp.minimum(r, R - 1), R)
+            node = jnp.sum(rn * oh_r, -1, keepdims=True).astype(jnp.int32)
+            slot = jnp.sum(rs * oh_r, -1, keepdims=True).astype(jnp.int32)
+            vslot = node * slots_per_node + slot
+            mine = node == own
+        else:
+            vslot = e_k
+            # node == own  <=>  e in [own * spn, (own + 1) * spn)
+            mine = ((vslot >= own * slots_per_node)
+                    & (vslot < (own + 1) * slots_per_node))
+        valid = (own < 0) | mine                              # (Tb, 1)
+        bucket_ref[:, k:k + 1] = vslot
+        valid_ref[:, k:k + 1] = valid.astype(jnp.int32)
+        # only valid entries occupy a capacity slot
+        ohs.append(_one_hot(vslot, n_buckets) * valid.astype(jnp.float32))
+    counts_ref[...] = counts
+
+    # capacity-slot positions in dispatch_indices' token-major order: the
+    # entries ahead of (t, k) are every (t' < t, any k') plus (t, k' < k).
+    # The first part is one strictly-lower-triangular 0/1 matmul over the
+    # per-token bucket totals (exact: 0/1 and small-integer operands,
+    # f32 accumulation, counts far below 2^24).
+    per_tok = sum(ohs)                                        # (Tb, B)
+    row = jax.lax.broadcasted_iota(jnp.int32, (tb, tb), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (tb, tb), 1)
+    lower = (col < row).astype(jnp.float32)
+    ahead = _dot(lower, per_tok) + base_ref[...]              # (Tb, B)
+    for k in range(top_k):
+        pos = jnp.sum(ahead * ohs[k], axis=-1, keepdims=True)
+        pos_ref[:, k:k + 1] = pos.astype(jnp.int32)
+        ahead = ahead + ohs[k]
+    base_ref[...] = base_ref[...] + jnp.sum(per_tok, axis=0, keepdims=True)
+
+
+def _token_block(T: int, tb: int) -> int:
+    """Token block: all of T when it fits in ``tb``, else ``tb`` halved
+    until it divides T (no lower than 8, the sublane count a partial
+    block needs); all of T when no such block divides it."""
+    if T <= tb:
+        return T
+    while T % tb and tb > 8:
+        tb //= 2
+    return tb if T % tb == 0 else T
 
 
 @functools.partial(jax.jit, static_argnames=("top_k", "n_buckets",
@@ -197,7 +241,7 @@ def gating_dispatch(x: jax.Array, w_router: jax.Array, top_k: int,
                     bias=None, count_weights=None, owner=None,
                     rep_node=None, rep_slot=None, rep_cum=None,
                     slots_per_node: int = 0, tb: int = 256,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """Fused router → top-k → dispatch-index build.
 
     x: (T, d), w_router: (d, E).  Returns
@@ -217,7 +261,8 @@ def gating_dispatch(x: jax.Array, w_router: jax.Array, top_k: int,
     (``bucket // slots_per_node``) equals ``owner`` occupy a slot, and
     the returned buffers cover that node's ``slots_per_node`` local
     buckets (rows = slots_per_node).  None keeps every pair and returns
-    global (rows = n_buckets) buffers.
+    global (rows = n_buckets) buffers.  It reaches the kernel in SMEM as
+    a scalar-prefetch argument.
 
     ``rep_node``/``rep_slot``/``rep_cum``: optional (E, R) live placement
     tables (``core.load_balance.PlacementTables``); the kernel then maps
@@ -229,52 +274,50 @@ def gating_dispatch(x: jax.Array, w_router: jax.Array, top_k: int,
     use_tables = rep_node is not None
     if not slots_per_node:
         slots_per_node = n_buckets
-    while T % tb:
-        tb //= 2
-    tb = max(tb, 1)
+    tb = _token_block(T, tb)
     grid = (T // tb,)
     b = (jnp.zeros((E,), jnp.float32) if bias is None else bias)
     cw = (jnp.ones((T,), jnp.float32) if count_weights is None
           else count_weights.astype(jnp.float32))
-    own = (jnp.full((1, 1), -1, jnp.int32) if owner is None
-           else jnp.asarray(owner, jnp.int32).reshape(1, 1))
-    inputs = [x, w_router, b.astype(jnp.float32).reshape(1, E),
-              cw.reshape(T, 1), own]
+    own = (jnp.full((1,), -1, jnp.int32) if owner is None
+           else jnp.asarray(owner, jnp.int32).reshape(1))
+    inputs = [own, x, w_router, b.astype(jnp.float32).reshape(1, E),
+              cw.reshape(T, 1)]
     in_specs = [
-        pl.BlockSpec((tb, d), lambda i: (i, 0)),
-        pl.BlockSpec((d, E), lambda i: (0, 0)),
-        pl.BlockSpec((1, E), lambda i: (0, 0)),
-        pl.BlockSpec((tb, 1), lambda i: (i, 0)),
-        pl.BlockSpec((1, 1), lambda i: (0, 0)),
+        pl.BlockSpec((tb, d), lambda i, o: (i, 0)),
+        pl.BlockSpec((d, E), lambda i, o: (0, 0)),
+        pl.BlockSpec((1, E), lambda i, o: (0, 0)),
+        pl.BlockSpec((tb, 1), lambda i, o: (i, 0)),
     ]
     if use_tables:
         R = rep_cum.shape[-1]
         inputs += [rep_node.astype(jnp.int32), rep_slot.astype(jnp.int32),
                    rep_cum.astype(jnp.float32)]
-        in_specs += [pl.BlockSpec((E, R), lambda i: (0, 0))] * 3
+        in_specs += [pl.BlockSpec((E, R), lambda i, o: (0, 0))] * 3
+    tok_spec = pl.BlockSpec((tb, top_k), lambda i, o: (i, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=[tok_spec] * 4 + [
+            pl.BlockSpec((None, 1, E), lambda i, o: (i, 0, 0))],
+        scratch_shapes=[pltpu.VMEM((1, n_buckets), jnp.float32)],
+    )
     gates, bucket, pos, valid, counts = pl.pallas_call(
         functools.partial(_dispatch_kernel, top_k=top_k,
                           n_buckets=n_buckets,
                           slots_per_node=slots_per_node, tb=tb,
                           use_tables=use_tables),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((tb, top_k), lambda i: (i, 0)),
-            pl.BlockSpec((tb, top_k), lambda i: (i, 0)),
-            pl.BlockSpec((tb, top_k), lambda i: (i, 0)),
-            pl.BlockSpec((tb, top_k), lambda i: (i, 0)),
-            pl.BlockSpec((1, E), lambda i: (i, 0)),
-        ],
+        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((T, top_k), jnp.float32),
             jax.ShapeDtypeStruct((T, top_k), jnp.int32),
             jax.ShapeDtypeStruct((T, top_k), jnp.int32),
             jax.ShapeDtypeStruct((T, top_k), jnp.int32),
-            jax.ShapeDtypeStruct((grid[0], E), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], 1, E), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, n_buckets), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="gating_dispatch",
     )(*inputs)
 
     # (rows, C) buffer scatter — stays jnp (no in-kernel scatter on TPU)
@@ -295,4 +338,4 @@ def gating_dispatch(x: jax.Array, w_router: jax.Array, top_k: int,
     idx_buf = idx_buf.at[bf, sf].set(tok.reshape(-1), mode="drop")
     gate_buf = jnp.zeros((rows, capacity), jnp.float32)
     gate_buf = gate_buf.at[bf, sf].set(gates.reshape(-1), mode="drop")
-    return idx_buf, gate_buf, jnp.sum(counts, axis=0)
+    return idx_buf, gate_buf, jnp.sum(counts, axis=(0, 1))

@@ -13,10 +13,13 @@ inputs so the same kernel serves smoke-scale tests.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import interpret_mode
 
 
 def _tile(dim: int, want: int) -> int:
@@ -42,7 +45,7 @@ def _kernel(x_ref, w_ref, o_ref, *, nk: int):
 @functools.partial(jax.jit, static_argnames=("mb", "nb", "kb", "interpret"))
 def grouped_matmul(x: jax.Array, w: jax.Array, *, mb: int = 128,
                    nb: int = 128, kb: int = 512,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: Optional[bool] = None) -> jax.Array:
     """(G, M, K) @ (G, K, N) -> (G, M, N) per-group matmul.
 
     VMEM working set per step: Mb*Kb + Kb*Nb (bf16) + Mb*Nb (f32 acc);
@@ -62,6 +65,7 @@ def grouped_matmul(x: jax.Array, w: jax.Array, *, mb: int = 128,
         ],
         out_specs=pl.BlockSpec((1, Mb, Nb), lambda g, i, j, k: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((G, M, N), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="grouped_matmul",
     )(x, w)
     return out.astype(x.dtype)

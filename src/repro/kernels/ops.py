@@ -1,64 +1,22 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Public entry points of the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels target TPU and are validated in interpret mode against the
-ref.py oracles).  On a real TPU backend set REPRO_PALLAS_INTERPRET=0 or
-pass interpret=False.
+On a TPU backend every kernel compiles with Mosaic; asking for
+interpret mode there raises (``kernels.platform.interpret_mode``).  Off
+TPU the kernels run in the Pallas interpreter, which is how the CPU
+tests check them against the ``ref.py`` oracles.
 """
 from __future__ import annotations
 
-import os
-
-import jax
 import jax.numpy as jnp
 
-from repro.kernels.decode_attention import decode_attention as _decode_attention
-from repro.kernels.decode_attention import (
-    paged_decode_attention as _paged_decode_attention)
-from repro.kernels.gating_topk import gating_dispatch as _gating_dispatch
-from repro.kernels.gating_topk import gating_topk as _gating_topk
-from repro.kernels.grouped_matmul import grouped_matmul as _grouped_matmul
+from repro.kernels.decode_attention import (decode_attention,
+                                            paged_decode_attention)
+from repro.kernels.gating_topk import gating_dispatch, gating_topk
+from repro.kernels.grouped_matmul import grouped_matmul
 from repro.models.common import activation
 
-
-def _default_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
-
-
-def grouped_matmul(x, w, **kw):
-    kw.setdefault("interpret", _default_interpret())
-    return _grouped_matmul(x, w, **kw)
-
-
-def gating_topk(x, w_router, top_k, **kw):
-    kw.setdefault("interpret", _default_interpret())
-    return _gating_topk(x, w_router, top_k, **kw)
-
-
-def gating_dispatch(x, w_router, top_k, n_buckets, capacity, **kw):
-    """Fused router → top-k → dispatch-index build (the serving hot
-    path's replacement for the route + dispatch_indices chain; see
-    ``kernels.gating_topk.gating_dispatch`` for the full contract)."""
-    kw.setdefault("interpret", _default_interpret())
-    return _gating_dispatch(x, w_router, top_k, n_buckets, capacity, **kw)
-
-
-def decode_attention(q, k_cache, v_cache, cache_pos, pos, **kw):
-    kw.setdefault("interpret", _default_interpret())
-    return _decode_attention(q, k_cache, v_cache, cache_pos, pos, **kw)
-
-
-def paged_decode_attention(q, k_pages, v_pages, pos_pages, block_table,
-                           pos, **kw):
-    """Block-table-indexed decode attention over a paged KV pool (the
-    paged-layout analogue of ``decode_attention``; see
-    ``kernels.decode_attention.paged_decode_attention``)."""
-    kw.setdefault("interpret", _default_interpret())
-    return _paged_decode_attention(q, k_pages, v_pages, pos_pages,
-                                   block_table, pos, **kw)
+__all__ = ["decode_attention", "gating_dispatch", "gating_topk",
+           "grouped_matmul", "grouped_mlp", "paged_decode_attention"]
 
 
 def grouped_mlp(xe, w1, w3, w2, act: str = "silu", row_valid=None, **kw):

@@ -10,10 +10,7 @@ import jax
 
 
 def _axis_type_kwargs(n_axes: int) -> dict:
-    # jax >= 0.5 takes explicit axis_types; 0.4.x has implicit Auto axes
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

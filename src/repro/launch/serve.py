@@ -13,6 +13,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 from typing import Optional
 
@@ -23,6 +24,7 @@ import numpy as np
 from repro.config import get_config, reduced
 from repro.core.disagg import STAGES, DisaggPlan, DisaggregatedInstance
 from repro.core.transport import HOP_KINDS, make_transport
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import split_serving_devices
 from repro.models import init_params
 from repro.serving.config import RUNTIMES, ServingConfig
@@ -127,7 +129,17 @@ def run(arch: Optional[str] = None, *,
     weight hop goes through (``core.transport``): "inproc" (the
     single-process device_put path), "simrdma" (same movement + an
     alpha-beta RDMA latency model per hop), or "multi"
-    (``jax.distributed`` multi-controller)."""
+    (``jax.distributed`` multi-controller).
+
+    ``n_layers`` > 0 serves the config cut to that depth, every width
+    kept (with ``use_reduced=False``: the published widths); ``dtype``
+    is the weights' and KV cache's dtype.  Parameters are initialised
+    in one jitted program, so each weight is drawn and cast in place
+    (no float32 copy of a bfloat16 model).
+
+    Returns the measured stats; the served ``Engine`` rides along under
+    ``"engine"`` for callers that inspect it after the run (its
+    ``first_logits``, its runtime's compiled phases)."""
     if arch is not None:
         overrides.setdefault("arch", arch)
     sc = (ServingConfig(**overrides) if config is None
@@ -135,7 +147,11 @@ def run(arch: Optional[str] = None, *,
     cfg = get_config(sc.arch)
     if sc.use_reduced:
         cfg = reduced(cfg)
-    params = init_params(cfg, jax.random.PRNGKey(sc.seed))
+    if sc.n_layers:
+        cfg = dataclasses.replace(cfg, n_layers=sc.n_layers)
+    dtype = jnp.dtype(sc.dtype)
+    params = jax.jit(init_params, static_argnums=(0, 2))(
+        cfg, jax.random.PRNGKey(sc.seed), dtype)
     if sc.zipf_route_bias > 0.0:
         if cfg.moe is None:
             raise ValueError("--zipf-route-bias needs an MoE arch")
@@ -183,15 +199,18 @@ def run(arch: Optional[str] = None, *,
         raise ValueError("--expert-rebalance-every needs "
                          "--runtime disagg|pingpong")
 
+    if inst is not None:
+        # the attention group owns the KV cache
+        engine_kw.update(kv_sharding=inst.kv_sharding)
     if prefill_devs:
         engine_kw.update(
             prefill_worker=PrefillWorker(
                 cfg, params, prefill_devs, max_seq=sc.max_seq,
                 chunk_tokens=sc.prefill_chunk_tokens,
-                page_size=sc.page_size if sc.kv_layout == "paged" else 0),
-            kv_sharding=inst.kv_sharding if inst is not None else None)
+                page_size=sc.page_size if sc.kv_layout == "paged" else 0))
 
-    eng = Engine(cfg, params, config=sc, transport=transport, **engine_kw)
+    eng = Engine(cfg, params, config=sc, transport=transport, dtype=dtype,
+                 **engine_kw)
     rng = np.random.RandomState(sc.seed)
     # shared-system-prompt workload: every request opens with the same
     # ``shared_prefix_len`` tokens (the pattern the radix prefix cache
@@ -261,6 +280,7 @@ def run(arch: Optional[str] = None, *,
             stats["kv_pages"][k] -= pre.get("kv_pages", {}).get(k, 0)
     stats["wall_s"] = dt
     stats["decode_tok_per_s"] = stats["tokens"] / dt
+    stats["engine"] = eng
     if sc.verbose:
         print(f"{sc.arch} [{sc.runtime}"
               f"{'+disagg-prefill' if prefill_devs else ''}] served "
@@ -300,6 +320,12 @@ def main():
                          "--reduced — full-scale params don't fit a "
                          "local host)")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--n-layers", type=int, default=0,
+                    help="serve the config cut to this many layers, every "
+                         "width kept (0 = the config's own depth)")
+    ap.add_argument("--dtype", default="float32",
+                    choices=("float32", "bfloat16"),
+                    help="dtype of the weights and the KV cache")
     ap.add_argument("--runtime", default="monolithic", choices=RUNTIMES)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=8)
@@ -314,7 +340,8 @@ def main():
     ap.add_argument("--kernels", action="store_true", dest="use_kernels",
                     help="run the decode hot path on the Pallas kernels "
                          "(flash decode attention, fused gating+dispatch, "
-                         "grouped expert MLP); interpret mode off-TPU")
+                         "grouped expert MLP); compiled on TPU, "
+                         "interpret mode off-TPU")
     ap.add_argument("--prefill-devices", type=int, default=0,
                     help="reserve N devices as a dedicated prefill "
                          "cluster (0 = inline prefill on the decode "
@@ -386,6 +413,7 @@ def main():
     if args.arch is None and not args.reduced:
         ap.error("pass --arch, or --reduced to serve the default "
                  "mixtral-8x22b at reduced scale")
+    enable_compile_cache()
     run(config=ServingConfig.from_args(args))
 
 

@@ -16,6 +16,7 @@ from typing import Union
 from repro.serving.sampler import SamplingParams
 
 RUNTIMES = ("monolithic", "disagg", "pingpong")
+DTYPES = ("float32", "bfloat16")
 TRANSFERS = ("sync", "async")
 ENGINE_MODES = ("monolithic", "pingpong")
 KV_LAYOUTS = ("contiguous", "paged")
@@ -33,6 +34,8 @@ class ServingConfig:
     # ---- workload / launcher ------------------------------------------
     arch: str = "mixtral-8x22b"
     use_reduced: bool = True
+    n_layers: int = 0                  # >0 cuts depth; widths stay as given
+    dtype: str = "float32"             # weights + KV cache: float32 | bfloat16
     runtime: str = "monolithic"        # monolithic | disagg | pingpong
     n_requests: int = 8
     max_new: int = 8
@@ -71,6 +74,11 @@ class ServingConfig:
         self.validate()
 
     def validate(self) -> "ServingConfig":
+        if self.dtype not in DTYPES:
+            raise ValueError(f"dtype must be one of {DTYPES}, "
+                             f"got {self.dtype!r}")
+        if self.n_layers < 0:
+            raise ValueError(f"n_layers must be >= 0, got {self.n_layers}")
         if self.runtime not in RUNTIMES:
             raise ValueError(f"runtime must be one of {RUNTIMES}, "
                              f"got {self.runtime!r}")

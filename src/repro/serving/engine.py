@@ -224,6 +224,8 @@ class Engine:
             self.page_pool = None
             self.prefix = None
             self.cache = init_cache(cfg, max_batch, max_seq, dtype)
+            if kv_sharding is not None:
+                self.cache = jax.device_put(self.cache, kv_sharding)
         # paged disaggregated prefill shares one pool/prefix tree with
         # the worker (single-process: the transport hop still prices the
         # page movement onto the decode placement)
@@ -256,6 +258,11 @@ class Engine:
                 self.params, cfg, toks, cache, pos,
                 use_kernels=base.use_kernels))
         self._last_token = [0] * max_batch
+        # logits (B, V) of the first and the latest decode iteration:
+        # every runtime decodes the same first step from the same
+        # prefill, so first_logits is what runtime-parity checks compare
+        self.first_logits: Optional[jax.Array] = None
+        self.last_logits: Optional[jax.Array] = None
         self.n_decode_iters = 0
         self.n_prefills = 0
         self.prefill_worker = prefill_worker
@@ -585,6 +592,9 @@ class Engine:
         else:
             self.cache = cache
         self.t_decode += time.perf_counter() - t0
+        if self.first_logits is None:
+            self.first_logits = logits
+        self.last_logits = logits
         self.key, k = jax.random.split(self.key)
         # per-request key folding: sampled tokens must not depend on
         # which KV row a request occupies (engines pack rows differently)

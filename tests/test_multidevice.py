@@ -335,8 +335,6 @@ with mesh:
                 in_shardings=(psh, tok_sh, csh, tok_sh))
     compiled = f.lower(pstructs, tok, cstructs, tok).compile()
 cost = compiled.cost_analysis()
-if isinstance(cost, list):  # jax 0.4.x returns [dict], newer returns dict
-    cost = cost[0]
 assert cost.get("flops", 0) > 0
 print("MINI-DRYRUN-OK flops=%.2e" % cost["flops"])
 """)
