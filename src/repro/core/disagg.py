@@ -36,7 +36,6 @@ stage here and are served by the monolithic engine instead.
 """
 from __future__ import annotations
 
-import time
 from collections import deque
 from dataclasses import dataclass
 from typing import List, Optional, Sequence
@@ -47,6 +46,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.config import ModelConfig
 from repro.core import load_balance as lb_lib
 from repro.core import m2n as m2n_lib
@@ -231,7 +231,7 @@ class DisaggregatedInstance:
         else:
             self.placement_slots = 0
 
-        self.reset_stage_times()
+        self.obs = obs.Recorder()       # disagg.<stage> spans
         self.reset_expert_counts()
         self.last_trace: List[tuple] = []
         # stage -> (jitted program, args) of its latest call; read only
@@ -251,6 +251,7 @@ class DisaggregatedInstance:
         cfg = self.cfg
         rep_e = NamedSharding(self.expert_mesh, P())
 
+        @jax.named_scope("attn")
         def attn_phase(p, x, act, cache, pos, window, tbl=None):
             delta, new_cache = self_attn_decode_sublayer(
                 p, cfg, x, pos, cache, window,
@@ -305,6 +306,7 @@ class DisaggregatedInstance:
             return x, h, new_cache, {"xe": xe, "idx": idx_buf,
                                      "gates": gate_buf, "counts": counts}
 
+        @jax.named_scope("expert")
         def expert_phase_moe(pe, xe):
             if self.plan.use_kernels:
                 from repro.kernels import ops as kops
@@ -317,9 +319,11 @@ class DisaggregatedInstance:
             h = h * jnp.einsum("ecd,edf->ecf", xe, pe["we3"])
             return jnp.einsum("ecf,efd->ecd", h, pe["we2"])
 
+        @jax.named_scope("expert")
         def expert_phase_dense(pe, h):
             return gated_ffn(h, pe["w1"], pe["w3"], pe["w2"], cfg.act)
 
+        @jax.named_scope("expert")
         def expert_phase_m2n(pe, router_p, h, act, tbl=None):
             if tbl is not None:
                 tbl = dict(tbl, slots_per_node=self.placement_slots)
@@ -343,6 +347,7 @@ class DisaggregatedInstance:
                 y = rms_norm(y, p["ln2_post"])
             return x + y
 
+        @jax.named_scope("combine")
         def combine_phase(p, x, h, out, idx_buf, gate_buf):
             T, d = x.shape
             y = jnp.zeros((T, d), jnp.float32)
@@ -350,11 +355,13 @@ class DisaggregatedInstance:
             y = y.at[idx_buf.reshape(-1)].add(w.reshape(-1, d), mode="drop")
             return combine_tail(p, x, h, y.astype(x.dtype))
 
+        @jax.named_scope("combine")
         def combine_m2n(p, x, h, y):
             # y: (T, d) routed output, already gate-weighted and combined
             # on the expert shards
             return combine_tail(p, x, h, y)
 
+        @jax.named_scope("combine")
         def combine_dense(p, x, out):
             if cfg.use_post_norm:
                 out = rms_norm(out, p["ln2_post"])
@@ -558,22 +565,20 @@ class DisaggregatedInstance:
     # ------------------------------------------------------- stage timing
     def reset_stage_times(self):
         """Zero the cumulative per-stage wall-clock accounting."""
-        self.stage_times = {s: 0.0 for s in STAGES}
-        self.stage_counts = {s: 0 for s in STAGES}
+        self.obs.reset()
 
-    def _timed(self, stage: str, fn, *args):
-        """Run one pipeline stage, accounting wall time to ``stage``.
+    def _timed(self, stage: str, mb: int, layer: int, fn, *args):
+        """Run one pipeline stage of micro-batch ``mb`` at ``layer``
+        inside the span ``disagg.<stage>``.
 
         Non-profiling mode measures host issue time only (the pipeline
         stays fully async); ``plan.profile_stages`` blocks on the result
         so the numbers reflect device execution (and serialise the
         pipeline — use for measurement, not serving)."""
-        t0 = time.perf_counter()
-        out = fn(*args)
-        if self.plan.profile_stages:
-            jax.block_until_ready(out)
-        self.stage_times[stage] += time.perf_counter() - t0
-        self.stage_counts[stage] += 1
+        with self.obs.span(f"disagg.{stage}", mb=mb, layer=layer):
+            out = fn(*args)
+            if self.plan.profile_stages:
+                jax.block_until_ready(out)
         if hasattr(fn, "lower"):
             self._last_calls[stage] = (fn, args)
         return out
@@ -589,16 +594,15 @@ class DisaggregatedInstance:
         """Cumulative per-stage seconds/counts plus the paper's per-op
         T_a / T_e / T_c estimates (attention-side compute, expert
         compute, one communication hop)."""
-        rep = {f"{s}_s": self.stage_times[s] for s in STAGES}
-        rep.update({f"{s}_n": self.stage_counts[s] for s in STAGES})
-        n = max(1, self.stage_counts["attn"])
-        rep["t_a"] = (self.stage_times["attn"]
-                      + self.stage_times["combine"]) / n
-        rep["t_e"] = self.stage_times["expert"] / max(
-            1, self.stage_counts["expert"])
-        n_hops = max(1, self.stage_counts["m2n"] + self.stage_counts["n2m"])
-        rep["t_c"] = (self.stage_times["m2n"]
-                      + self.stage_times["n2m"]) / n_hops
+        tot = self.obs.totals()
+        n_of = {s: tot.get(f"disagg.{s}", (0, 0.0))[0] for s in STAGES}
+        s_of = {s: tot.get(f"disagg.{s}", (0, 0.0))[1] for s in STAGES}
+        rep = {f"{s}_s": s_of[s] for s in STAGES}
+        rep.update({f"{s}_n": n_of[s] for s in STAGES})
+        rep["t_a"] = (s_of["attn"] + s_of["combine"]) / max(1, n_of["attn"])
+        rep["t_e"] = s_of["expert"] / max(1, n_of["expert"])
+        rep["t_c"] = (s_of["m2n"] + s_of["n2m"]) / max(
+            1, n_of["m2n"] + n_of["n2m"])
         return rep
 
     def measure_stage_times(self, batch: int, max_seq: int = 32) -> dict:
@@ -687,26 +691,27 @@ class DisaggregatedInstance:
             def drain_one():
                 i, x, h, out, disp = inflight.popleft()
                 out_back = self._timed(                        # N2M return
-                    "n2m", self._send_n2m, out)
+                    "n2m", i, l, self._send_n2m, out)
                 if cfg.moe is not None and self.plan.use_m2n:
-                    xs[i] = self._timed("combine", self._combine_m2n,
+                    xs[i] = self._timed("combine", i, l, self._combine_m2n,
                                         pa, x, h, out_back)
                 elif cfg.moe is not None:
-                    xs[i] = self._timed("combine", self._combine, pa, x, h,
-                                        out_back, disp["idx"], disp["gates"])
+                    xs[i] = self._timed("combine", i, l, self._combine, pa,
+                                        x, h, out_back, disp["idx"],
+                                        disp["gates"])
                 else:
-                    xs[i] = self._timed("combine", self._combine_dense,
+                    xs[i] = self._timed("combine", i, l, self._combine_dense,
                                         pa, x, out_back)
 
             for i, s in enumerate(mbs):
                 entry = self._cache_entry(cache, l, s)
                 if placed and not self.plan.use_m2n:
                     x, h, new_entry, disp = self._timed(
-                        "attn", self._attn_phase_placed[window], pa,
+                        "attn", i, l, self._attn_phase_placed[window], pa,
                         self._tables_dev, xs[i], acts[i], entry, poss[i])
                 else:
                     x, h, new_entry, disp = self._timed(
-                        "attn", self._attn_phase[window], pa, xs[i],
+                        "attn", i, l, self._attn_phase[window], pa, xs[i],
                         acts[i], entry, poss[i])
                 if disp is not None and "counts" in disp:
                     # lazy device add — the live traffic trace for the
@@ -717,22 +722,23 @@ class DisaggregatedInstance:
                 # M2N dispatch hop: routed capacity buffers in the
                 # baseline path, raw (T, d) activations in the m2n path
                 payload = h if disp is None else disp["xe"]
-                buf = self._timed("m2n", self._send_m2n, payload)
+                buf = self._timed("m2n", i, l, self._send_m2n, payload)
                 if cfg.moe is not None and self.plan.use_m2n:
                     if placed:
                         out, cnt = self._timed(
-                            "expert", self._expert_phase_placed, pe,
+                            "expert", i, l, self._expert_phase_placed, pe,
                             self.layers_router_ep[l], self._tables_dev_ep,
                             buf, acts[i])
                     else:
                         out, cnt = self._timed(
-                            "expert", self._expert_phase, pe,
+                            "expert", i, l, self._expert_phase, pe,
                             self.layers_router_ep[l], buf, acts[i])
                     self._counts_ep = self._counts_ep + cnt
                     self._account_combine(payload.shape[0], payload.shape[1],
                                           payload.dtype.itemsize)
                 else:
-                    out = self._timed("expert", self._expert_phase, pe, buf)
+                    out = self._timed("expert", i, l, self._expert_phase, pe,
+                                      buf)
                 trace.append(("expert", i, l))
                 inflight.append((i, x, h, out, disp))
                 # double buffer: one micro-batch computing on the expert

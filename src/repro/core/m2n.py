@@ -145,52 +145,60 @@ def sharded_routed_experts(params: dict, x: jax.Array, cfg: MoEConfig,
             # never consume the aux loss, so it is pinned to 0 here.
             from repro.kernels import ops as kops
             tk = dict(zip(("rep_node", "rep_slot", "rep_cum"), tbl))
-            idx_buf, gate_buf, counts = kops.gating_dispatch(
-                x_all, router_w, cfg.top_k, n_buckets=n_shards * e_loc,
-                capacity=cap, bias=bias, count_weights=cw, owner=j,
-                slots_per_node=e_loc, **tk)
-            aux = jnp.zeros((), jnp.float32)
-            xe = x_all.at[idx_buf].get(mode="fill", fill_value=0)
+            with jax.named_scope("m2n_dispatch"):
+                idx_buf, gate_buf, counts = kops.gating_dispatch(
+                    x_all, router_w, cfg.top_k, n_buckets=n_shards * e_loc,
+                    capacity=cap, bias=bias, count_weights=cw, owner=j,
+                    slots_per_node=e_loc, **tk)
+                aux = jnp.zeros((), jnp.float32)
+                xe = x_all.at[idx_buf].get(mode="fill", fill_value=0)
             # 3'. grouped per-expert MLP kernel, dropped/empty capacity
             #     slots masked to exact zeros
-            out = kops.grouped_mlp(xe, w1, w3, w2, act,
-                                   row_valid=idx_buf < t_all)
+            with jax.named_scope("experts"):
+                out = kops.grouped_mlp(xe, w1, w3, w2, act,
+                                       row_valid=idx_buf < t_all)
         else:
-            # 1. routing — replicated across the expert axis (paper:
-            #    gating is fused on the attention side; every expert
-            #    shard knows the plan)
-            routing = moe_lib.route(x_all, router_w, cfg.top_k, bias)
-            aux = moe_lib.load_balance_loss(routing, E)
-            counts = moe_lib.routing_counts(routing, E, cw)
-            if tbl:
-                # placement-table ownership: token-hash replica assignment
-                vslot, node = moe_lib.replica_assign(routing.experts, *tbl,
-                                                     slots_per_node=e_loc)
-                local = node == j
-                local_ids = jnp.where(local, vslot - j * e_loc, 0)
-            else:
-                owner = routing.experts // e_loc
-                local = owner == j
-                local_ids = jnp.where(local, routing.experts - j * e_loc, 0)
-            # 2. dispatch: gather ONLY locally-routed tokens — no wire
-            #    traffic
-            r_loc = moe_lib.Routing(routing.gates, local_ids, routing.probs)
-            idx_buf, gate_buf = moe_lib.dispatch_indices(r_loc, e_loc, cap,
-                                                         valid=local)
-            xe = x_all.at[idx_buf].get(mode="fill", fill_value=0)
+            with jax.named_scope("m2n_dispatch"):
+                # 1. routing — replicated across the expert axis (paper:
+                #    gating is fused on the attention side; every expert
+                #    shard knows the plan)
+                routing = moe_lib.route(x_all, router_w, cfg.top_k, bias)
+                aux = moe_lib.load_balance_loss(routing, E)
+                counts = moe_lib.routing_counts(routing, E, cw)
+                if tbl:
+                    # placement-table ownership: token-hash replica
+                    # assignment
+                    vslot, node = moe_lib.replica_assign(
+                        routing.experts, *tbl, slots_per_node=e_loc)
+                    local = node == j
+                    local_ids = jnp.where(local, vslot - j * e_loc, 0)
+                else:
+                    owner = routing.experts // e_loc
+                    local = owner == j
+                    local_ids = jnp.where(local, routing.experts - j * e_loc,
+                                          0)
+                # 2. dispatch: gather ONLY locally-routed tokens — no wire
+                #    traffic
+                r_loc = moe_lib.Routing(routing.gates, local_ids,
+                                        routing.probs)
+                idx_buf, gate_buf = moe_lib.dispatch_indices(
+                    r_loc, e_loc, cap, valid=local)
+                xe = x_all.at[idx_buf].get(mode="fill", fill_value=0)
             # 3. complete per-expert GEMMs on the local shard (d_ff
             #    possibly sliced over the data axes in weights_2d mode)
-            h = activation(jnp.einsum("ecd,edf->ecf", xe, w1), act)
-            h = h * jnp.einsum("ecd,edf->ecf", xe, w3)
-            out = jnp.einsum("ecf,efd->ecd", h, w2)
-            if weights_2d and dtuple:
-                out = jax.lax.psum(out, dtuple)    # reduce f-partials
+            with jax.named_scope("experts"):
+                h = activation(jnp.einsum("ecd,edf->ecf", xe, w1), act)
+                h = h * jnp.einsum("ecd,edf->ecf", xe, w3)
+                out = jnp.einsum("ecf,efd->ecd", h, w2)
+                if weights_2d and dtuple:
+                    out = jax.lax.psum(out, dtuple)    # reduce f-partials
         # 4. combine: weighted partial sum, reduced over the expert axis.
-        y = jnp.zeros((t_all, x_all.shape[1]), jnp.float32)
-        w = out.astype(jnp.float32) * gate_buf[..., None]
-        y = y.at[idx_buf.reshape(-1)].add(w.reshape(-1, x_all.shape[1]),
-                                          mode="drop")
-        y = jax.lax.psum(y, expert_axis)
+        with jax.named_scope("m2n_combine"):
+            y = jnp.zeros((t_all, x_all.shape[1]), jnp.float32)
+            w = out.astype(jnp.float32) * gate_buf[..., None]
+            y = y.at[idx_buf.reshape(-1)].add(
+                w.reshape(-1, x_all.shape[1]), mode="drop")
+            y = jax.lax.psum(y, expert_axis)
         if weights_2d and dtuple:
             # back to this shard's rows
             idx = jnp.zeros((), jnp.int32)

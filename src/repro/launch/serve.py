@@ -48,6 +48,14 @@ def _format_phases(ph: dict) -> str:
             f"decode={ph['decode_s'] * 1e3:.1f}ms/{ph['decode_n']}")
 
 
+def _format_counters(c: dict) -> str:
+    steps = max(1, c["decode_steps"])
+    return (f"counters: host_syncs={c['host_syncs'] / steps:.1f}/step "
+            f"programs_built={c['programs_built'] / steps:.2f}/step "
+            f"({c['programs_built']} built, {c['programs_from_cache']} "
+            f"from the persistent cache) admissions={c['admissions']}")
+
+
 def _format_transport(tr: dict) -> str:
     parts = []
     for kind in HOP_KINDS:
@@ -257,6 +265,8 @@ def run(arch: Optional[str] = None, *,
               "transfer_s", "transfer_n", "decode_s", "decode_n"):
         if k in stats["phases"]:
             stats["phases"][k] -= pre["phases"].get(k, 0)
+    for k in stats["counters"]:
+        stats["counters"][k] -= pre["counters"][k]
     for k in ("rebalances", "placement_updates", "rebalance_s"):
         if k in stats:
             stats[k] -= pre.get(k, 0)
@@ -289,6 +299,7 @@ def run(arch: Optional[str] = None, *,
               f"({stats['decode_tok_per_s']:.1f} tok/s, "
               f"{stats['decode_iters']} decode iters)")
         print(_format_phases(stats["phases"]))
+        print(_format_counters(stats["counters"]))
         print(_format_transport(stats["transport"]))
         if "kv_pages" in stats:
             kp = stats["kv_pages"]

@@ -167,23 +167,26 @@ def routed_experts_dense(params: dict, x: jax.Array, cfg: MoEConfig, act: str,
                          capacity_mode: str):
     """Baseline routed-expert computation (monolithic scatter/gather)."""
     T, d = x.shape
-    routing = route(x, params["router"], cfg.top_k,
-                    params.get("router_bias"))
-    aux = load_balance_loss(routing, cfg.n_experts)
-    C = expert_capacity(T, cfg, capacity_mode)
-    idx_buf, gate_buf = dispatch_indices(routing, cfg.n_experts, C)
+    with jax.named_scope("router"):
+        routing = route(x, params["router"], cfg.top_k,
+                        params.get("router_bias"))
+        aux = load_balance_loss(routing, cfg.n_experts)
+        C = expert_capacity(T, cfg, capacity_mode)
+        idx_buf, gate_buf = dispatch_indices(routing, cfg.n_experts, C)
 
-    # gather tokens into (E, C, d) expert buffers
-    xe = x.at[idx_buf].get(mode="fill", fill_value=0)
-    # per-expert gated MLP: (E,C,d) x (E,d,f) -> (E,C,f) -> (E,C,d)
-    h = activation(jnp.einsum("ecd,edf->ecf", xe, params["we1"]), act)
-    h = h * jnp.einsum("ecd,edf->ecf", xe, params["we3"])
-    out = jnp.einsum("ecf,efd->ecd", h, params["we2"])
+    with jax.named_scope("experts"):
+        # gather tokens into (E, C, d) expert buffers
+        xe = x.at[idx_buf].get(mode="fill", fill_value=0)
+        # per-expert gated MLP: (E,C,d) x (E,d,f) -> (E,C,f) -> (E,C,d)
+        h = activation(jnp.einsum("ecd,edf->ecf", xe, params["we1"]), act)
+        h = h * jnp.einsum("ecd,edf->ecf", xe, params["we3"])
+        out = jnp.einsum("ecf,efd->ecd", h, params["we2"])
 
-    # weighted scatter-add combine
-    y = jnp.zeros((T, d), dtype=jnp.float32)
-    w = out.astype(jnp.float32) * gate_buf[..., None]
-    y = y.at[idx_buf.reshape(-1)].add(w.reshape(-1, d), mode="drop")
+    with jax.named_scope("combine"):
+        # weighted scatter-add combine
+        y = jnp.zeros((T, d), dtype=jnp.float32)
+        w = out.astype(jnp.float32) * gate_buf[..., None]
+        y = y.at[idx_buf.reshape(-1)].add(w.reshape(-1, d), mode="drop")
     return y.astype(x.dtype), aux
 
 

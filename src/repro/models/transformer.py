@@ -196,6 +196,7 @@ def _ffn_sublayer(p, x2d_shape_x, cfg: ModelConfig, capacity_mode: str):
     return _maybe_post(p, "ln2_post", y, cfg), aux
 
 
+@jax.named_scope("attention")
 def _self_attn_sublayer(p, x, cfg: ModelConfig, positions, *, causal=True,
                         window=0, build_cache=False, cache_len=0, prefix=""):
     """Returns (delta, cache_entry_or_None)."""
@@ -301,6 +302,7 @@ def apply_layer_seq(kind: str, p: dict, cfg: ModelConfig, x: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("attention")
 def self_attn_decode_sublayer(p: dict, cfg: ModelConfig, x: jax.Array,
                               pos: jax.Array, cache: dict, window: int,
                               prefix: str = "", ln: str = "ln1",
@@ -489,6 +491,7 @@ def _encode(params: dict, cfg: ModelConfig, frames: jax.Array) -> jax.Array:
     return rms_norm(x, enc["final_norm"])
 
 
+@jax.named_scope("embed")
 def _embed_tokens(params, cfg, tokens):
     x = params["embed"][tokens]
     if cfg.tie_embeddings:
@@ -496,6 +499,7 @@ def _embed_tokens(params, cfg, tokens):
     return x
 
 
+@jax.named_scope("lm_head")
 def _lm_head(params, cfg, x):
     h = rms_norm(x, params["final_norm"])
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.config import ModelConfig
 from repro.core.load_balance import balance_experts, evaluate_placement
 from repro.core.transport import InProcessTransport
@@ -51,7 +52,8 @@ from repro.serving.pages import PagePool, n_pages_for
 from repro.serving.prefill import suffix_prefill
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.sampler import SamplingParams, sample, sample_rows
-from repro.serving.stats import STATS_SCHEMA_VERSION, EngineStats
+from repro.serving.stats import (STATS_SCHEMA_VERSION, CounterStats,
+                                  EngineStats)
 
 # sentinel distinguishing "kwarg not passed" from an explicit value, so
 # the deprecated scalar aliases below can coexist with ``config=``
@@ -268,18 +270,14 @@ class Engine:
         self.prefill_worker = prefill_worker
         self.transfer = transfer
         self.kv_sharding = kv_sharding
-        # per-phase host-issue wall time (prefill / KV transfer / decode)
-        self.t_prefill = 0.0
-        self.t_transfer = 0.0
-        self.t_decode = 0.0
-        self.n_transfers = 0
+        # host spans (engine.*) and counters of the scheduler
+        self.obs = obs.Recorder()
         # live expert load balancing (paper §6)
         self.expert_rebalance_every = expert_rebalance_every
         self.expert_replication = expert_replication
         self._load_window: deque = deque(maxlen=max(1, expert_window))
         self.n_rebalances = 0
         self.n_placement_updates = 0
-        self.t_rebalance = 0.0
         self._track_experts = (cfg.moe is not None and runtime is not None
                                and hasattr(runtime, "set_active_slots"))
 
@@ -296,11 +294,13 @@ class Engine:
         req.slot = slot
         self.key, k = jax.random.split(self.key)
         tok = int(sample(last_logits, k, self.sampling)[0])
+        self.obs.count("host_syncs")
         req.generated.append(tok)
         req.t_first_token = time.perf_counter()
         self._last_token[slot] = tok
         self.running[req.rid] = req
         self.n_prefills += 1
+        self.obs.count("admissions")
 
     # --------------------------------------------------------- paged helpers
     def _pages_for_request(self, req: Request) -> int:
@@ -370,26 +370,25 @@ class Engine:
                 break
             self.waiting.pop(0)
             slot = self.slots.alloc(req.rid)
-            t0 = time.perf_counter()
-            if h:
-                row = self.page_pool.gather_row(shared)
-                last_logits, row = suffix_prefill(
-                    self.params, self.cfg, req.prompt, row, h)
-            else:
-                toks = jnp.asarray([req.prompt], jnp.int32)
-                extras = extra_inputs(self.cfg, 1)
-                last_logits, row = prefill(self.params, self.cfg, toks,
-                                           max_seq=self.max_seq, **extras)
-            self.t_prefill += time.perf_counter() - t0
-            t0 = time.perf_counter()
-            n_written = n_pages_for(len(req.prompt), ps)
-            fresh = [self._take_page(req.rid)
-                     for _ in range(n_written - len(shared))]
-            if fresh:
-                self.page_pool.write_row_span(fresh, row, len(shared) * ps,
-                                              len(req.prompt))
-            self.t_transfer += time.perf_counter() - t0
-            self.n_transfers += 1
+            ids = {"rid": req.rid, "prompt_len": len(req.prompt)}
+            with self.obs.span("engine.prefill", **ids):
+                if h:
+                    row = self.page_pool.gather_row(shared)
+                    last_logits, row = suffix_prefill(
+                        self.params, self.cfg, req.prompt, row, h)
+                else:
+                    toks = jnp.asarray([req.prompt], jnp.int32)
+                    extras = extra_inputs(self.cfg, 1)
+                    last_logits, row = prefill(self.params, self.cfg, toks,
+                                               max_seq=self.max_seq,
+                                               **extras)
+            with self.obs.span("engine.insert", **ids):
+                n_written = n_pages_for(len(req.prompt), ps)
+                fresh = [self._take_page(req.rid)
+                         for _ in range(n_written - len(shared))]
+                if fresh:
+                    self.page_pool.write_row_span(
+                        fresh, row, len(shared) * ps, len(req.prompt))
             self._install_pages(req, shared, fresh)
             self._start_request(req, slot, last_logits)
 
@@ -414,13 +413,12 @@ class Engine:
             slot = self.slots.alloc(req.rid)
             fresh = [self._take_page(req.rid)
                      for _ in range(len(res.page_chunks))]
-            t0 = time.perf_counter()
-            migrate_pages(self.page_pool, res.page_chunks, fresh,
-                          sharding=self.kv_sharding,
-                          sync=self.transfer == "sync",
-                          transport=self.transport)
-            self.t_transfer += time.perf_counter() - t0
-            self.n_transfers += 1
+            with self.obs.span("engine.insert", rid=req.rid,
+                               prompt_len=len(req.prompt)):
+                migrate_pages(self.page_pool, res.page_chunks, fresh,
+                              sharding=self.kv_sharding,
+                              sync=self.transfer == "sync",
+                              transport=self.transport)
             self._install_pages(req, shared, fresh)
             self._start_request(req, slot, res.last_logits)
 
@@ -437,16 +435,14 @@ class Engine:
         while self.waiting and self.slots.free:
             req = self.waiting.pop(0)
             slot = self.slots.alloc(req.rid)
-            toks = jnp.asarray([req.prompt], jnp.int32)
-            extras = extra_inputs(self.cfg, 1)
-            t0 = time.perf_counter()
-            last_logits, rcache = prefill(self.params, self.cfg, toks,
-                                          max_seq=self.max_seq, **extras)
-            self.t_prefill += time.perf_counter() - t0
-            t0 = time.perf_counter()
-            self.cache = insert_rows(self.cache, rcache, slot)
-            self.t_transfer += time.perf_counter() - t0
-            self.n_transfers += 1
+            ids = {"rid": req.rid, "prompt_len": len(req.prompt)}
+            with self.obs.span("engine.prefill", **ids):
+                toks = jnp.asarray([req.prompt], jnp.int32)
+                extras = extra_inputs(self.cfg, 1)
+                last_logits, rcache = prefill(self.params, self.cfg, toks,
+                                              max_seq=self.max_seq, **extras)
+            with self.obs.span("engine.insert", **ids):
+                self.cache = insert_rows(self.cache, rcache, slot)
             self._start_request(req, slot, last_logits)
 
     def _admit_from_transfer_queue(self):
@@ -470,20 +466,19 @@ class Engine:
             res = w.pop()
             req = res.request
             slot = self.slots.alloc(req.rid)
-            t0 = time.perf_counter()
-            self.cache = migrate_kv(self.cache, res.kv, slot,
-                                    sharding=self.kv_sharding,
-                                    sync=self.transfer == "sync",
-                                    transport=self.transport)
-            self.t_transfer += time.perf_counter() - t0
-            self.n_transfers += 1
+            with self.obs.span("engine.insert", rid=req.rid,
+                               prompt_len=len(req.prompt)):
+                self.cache = migrate_kv(self.cache, res.kv, slot,
+                                        sharding=self.kv_sharding,
+                                        sync=self.transfer == "sync",
+                                        transport=self.transport)
             self._start_request(req, slot, res.last_logits)
 
     def _rebalance(self):
         """Drain one interval of live routing counts, re-solve placement
         over the sliding window, and apply it to the runtime (§6)."""
-        t0 = time.perf_counter()
         self._load_window.append(self.runtime.take_expert_counts())
+        self.obs.count("host_syncs")            # the counts read back
         loads = np.sum(self._load_window, axis=0)
         placement = balance_experts(
             loads, self.runtime.n_expert_nodes,
@@ -491,7 +486,6 @@ class Engine:
         if self.runtime.apply_placement(placement):
             self.n_placement_updates += 1
         self.n_rebalances += 1
-        self.t_rebalance += time.perf_counter() - t0
 
     def _retire(self):
         for rid in [r for r, q in self.running.items() if q.done]:
@@ -549,69 +543,83 @@ class Engine:
     def step(self) -> int:
         """One engine iteration: admit + one decode step.  Returns number
         of active requests decoded."""
+        with self.obs.span("engine.step", step=self.n_decode_iters):
+            return self._step()
+
+    def _step(self) -> int:
         # in pingpong mode, micro-batch-granular recycling lives in the
         # allocator: released slots return to their own group's free list
         # and admission refills the emptiest group — host-side work that
         # overlaps whatever device work is still in flight
-        self._retire()
-        self._admit()
+        span = self.obs.span
+        with span("engine.retire"):
+            self._retire()
+        with span("engine.admit"):
+            self._admit()
         if not self.running:
             return 0
-        toks = jnp.asarray(self._last_token, jnp.int32)
-        pos = jnp.zeros((self.max_batch,), jnp.int32)
-        for req in self.running.values():
-            pos = pos.at[req.slot].set(req.position - 1)
-        if self._track_experts:
-            # only live rows feed the routing-count traffic trace
-            active = np.zeros((self.max_batch,), np.float32)
+        with span("engine.prepare"):
+            toks = jnp.asarray(self._last_token, jnp.int32)
+            pos = jnp.zeros((self.max_batch,), jnp.int32)
             for req in self.running.values():
-                active[req.slot] = 1.0
-            self.runtime.set_active_slots(active)
-        t0 = time.perf_counter()
+                pos = pos.at[req.slot].set(req.position - 1)
+            if self._track_experts:
+                # only live rows feed the routing-count traffic trace
+                active = np.zeros((self.max_batch,), np.float32)
+                for req in self.running.values():
+                    active[req.slot] = 1.0
+                self.runtime.set_active_slots(active)
+            if self.kv_layout == "paged":
+                # block-table gather: materialize the dense (B, W) view
+                # the decode step expects.  The gather is a pure copy
+                # (unmapped pages read as pos=-1, exactly a reset row), so
+                # the decode computation below is bit-identical to the
+                # contiguous layout's across all runtimes and kernels.
+                bt = np.full((self.max_batch, self.page_pool.n_logical), -1,
+                             np.int32)
+                for req in self.running.values():
+                    tb = self.block_tables[req.rid]
+                    bt[req.slot, :len(tb)] = tb
+                cache = self.page_pool.gather(bt)
+            else:
+                cache = self.cache
+        with span("engine.decode"):
+            if self.mode == "pingpong":
+                logits, cache = self.runtime.decode_microbatched(
+                    toks, cache, pos, self.mb_slices)
+            else:
+                logits, cache = self._decode(toks, cache, pos)
         if self.kv_layout == "paged":
-            # block-table gather: materialize the dense (B, W) view the
-            # decode step expects.  The gather is a pure copy (unmapped
-            # pages read as pos=-1, exactly a reset row), so the decode
-            # computation below is bit-identical to the contiguous
-            # layout's across all runtimes and kernels.
-            bt = np.full((self.max_batch, self.page_pool.n_logical), -1,
-                         np.int32)
-            for req in self.running.values():
-                tb = self.block_tables[req.rid]
-                bt[req.slot, :len(tb)] = tb
-            cache = self.page_pool.gather(bt)
-        else:
-            cache = self.cache
-        if self.mode == "pingpong":
-            logits, cache = self.runtime.decode_microbatched(
-                toks, cache, pos, self.mb_slices)
-        else:
-            logits, cache = self._decode(toks, cache, pos)
-        if self.kv_layout == "paged":
-            self._paged_writeback(cache)
+            with span("engine.writeback"):
+                self._paged_writeback(cache)
         else:
             self.cache = cache
-        self.t_decode += time.perf_counter() - t0
         if self.first_logits is None:
             self.first_logits = logits
         self.last_logits = logits
-        self.key, k = jax.random.split(self.key)
-        # per-request key folding: sampled tokens must not depend on
-        # which KV row a request occupies (engines pack rows differently)
-        rids = np.zeros((self.max_batch,), np.int64)
-        for req in self.running.values():
-            rids[req.slot] = req.rid
-        nxt = sample_rows(logits, k, rids, self.sampling)
-        for req in self.running.values():
-            tok = int(nxt[req.slot])
-            req.generated.append(tok)
-            self._last_token[req.slot] = tok
+        with span("engine.sample"):
+            self.key, k = jax.random.split(self.key)
+            # per-request key folding: sampled tokens must not depend on
+            # which KV row a request occupies (engines pack rows
+            # differently)
+            rids = np.zeros((self.max_batch,), np.int64)
+            for req in self.running.values():
+                rids[req.slot] = req.rid
+            nxt = sample_rows(logits, k, rids, self.sampling)
+            for req in self.running.values():
+                tok = int(nxt[req.slot])
+                req.generated.append(tok)
+                self._last_token[req.slot] = tok
+        n_active = len(self.running)
+        self.obs.count("host_syncs", n_active)     # one int() per row
+        self.obs.count("decode_steps")
         self.n_decode_iters += 1
         if (self.expert_rebalance_every
                 and self.n_decode_iters % self.expert_rebalance_every == 0):
-            self._rebalance()
-        n_active = len(self.running)
-        self._retire()
+            with span("engine.rebalance"):
+                self._rebalance()
+        with span("engine.retire"):
+            self._retire()
         return n_active
 
     @property
@@ -629,6 +637,21 @@ class Engine:
         return self.finished
 
     # ------------------------------------------------------------- metrics
+    def counters(self) -> CounterStats:
+        """Cumulative counts of the scheduler's work: blocking
+        device-to-host reads, decode steps, admissions, and the programs
+        JAX built (``programs_from_cache`` of them loaded from the
+        persistent cache) inside the spans of the engine, its runtime or
+        its prefill worker."""
+        c = self.obs.counters()
+        out = {k: c.get(k, 0)
+               for k in ("host_syncs", "decode_steps", "admissions")}
+        recs = [x.obs for x in (self, self.runtime, self.prefill_worker)
+                if hasattr(x, "obs")]
+        for k in (obs.BUILT, obs.FROM_CACHE):
+            out[k] = sum(r.counters().get(k, 0) for r in recs)
+        return out
+
     def stats(self) -> EngineStats:
         lat = [r.t_done - r.t_submit for r in self.finished]
         toks = sum(len(r.generated) for r in self.finished)
@@ -648,19 +671,23 @@ class Engine:
             out["kv_pages"] = self.page_pool.stats()
         if self.prefix is not None:
             out["prefix_cache"] = self.prefix.stats()
-        # per-phase breakdown (host-issue wall time: the pipeline stays
-        # async — prefill/transfer overlap in-flight decode)
-        phases = {"transfer_s": self.t_transfer,
-                  "transfer_n": self.n_transfers,
+        # per-phase breakdown from the engine's span totals (host-issue
+        # wall time: the pipeline stays async — prefill/transfer overlap
+        # in-flight decode)
+        tot = self.obs.totals()
+        phases = {"transfer_s": self.obs.seconds("engine.insert"),
+                  "transfer_n": tot.get("engine.insert", (0,))[0],
                   "transfer_mode": self.transfer,
-                  "decode_s": self.t_decode,
+                  "decode_s": (self.obs.seconds("engine.decode")
+                               + self.obs.seconds("engine.writeback")),
                   "decode_n": self.n_decode_iters}
         if self.prefill_worker is not None:
             phases.update(self.prefill_worker.stats())
         else:
-            phases.update(prefill_s=self.t_prefill,
+            phases.update(prefill_s=self.obs.seconds("engine.prefill"),
                           prefills=self.n_prefills)
         out["phases"] = phases
+        out["counters"] = self.counters()
         # per-hop wire traffic, by kind (tokens / kv / weights /
         # collective) — the transport ledger shared with the runtime
         out["transport"] = self.transport.stats()
@@ -682,7 +709,7 @@ class Engine:
             out["expert_loads"] = loads.tolist()
             out["rebalances"] = self.n_rebalances
             out["placement_updates"] = self.n_placement_updates
-            out["rebalance_s"] = self.t_rebalance
+            out["rebalance_s"] = self.obs.seconds("engine.rebalance")
             n_replicas = (self.runtime.placement_fractions > 1e-9).sum(axis=1)
             out["replicated_experts"] = int((n_replicas > 1).sum())
         return out

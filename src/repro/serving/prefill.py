@@ -34,7 +34,6 @@ one request at a time to stay bit-identical with the inline path.
 from __future__ import annotations
 
 import functools
-import time
 from collections import deque
 from dataclasses import dataclass
 from typing import Callable, Optional, Sequence
@@ -44,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.config import ModelConfig
 from repro.models import decode_step, prefill as model_prefill
 from repro.models.stubs import extra_inputs
@@ -152,7 +152,7 @@ class PrefillWorker:
         self.n_prefills = 0
         self.n_batches = 0
         self.n_tokens = 0
-        self.t_prefill_s = 0.0
+        self.obs = obs.Recorder()       # prefill.batch spans
 
     # ------------------------------------------------------------- frontend
     def submit(self, request) -> None:
@@ -213,19 +213,19 @@ class PrefillWorker:
         """Radix-hit path: gather the cached prefix pages and compute
         only the suffix — the shared prefix is never re-run."""
         h, pages = self._hits.pop(req.rid)
-        t0 = time.perf_counter()
-        row = self.page_pool.gather_row(pages)
-        row = jax.device_put(row, NamedSharding(self.mesh, P()))
-        last_logits, row = suffix_prefill(self.params, self.cfg,
-                                          req.prompt, row, h)
-        greedy = jnp.argmax(last_logits, -1)
-        dt = time.perf_counter() - t0
-        self.t_prefill_s += dt
+        with self.obs.span("prefill.batch", size=1,
+                           prompt_len=len(req.prompt)) as sp:
+            row = self.page_pool.gather_row(pages)
+            row = jax.device_put(row, NamedSharding(self.mesh, P()))
+            last_logits, row = suffix_prefill(self.params, self.cfg,
+                                              req.prompt, row, h)
+            greedy = jnp.argmax(last_logits, -1)
         self.n_batches += 1
         self.ready.append(PrefillResult(
             request=req, last_logits=last_logits,
             first_token=greedy[0], n_prompt_tokens=len(req.prompt),
-            t_prefill_s=dt, **self._paged_fields(req, row, h, pages)))
+            t_prefill_s=sp.seconds,
+            **self._paged_fields(req, row, h, pages)))
         self.n_prefills += 1
         self.n_tokens += len(req.prompt) - h
 
@@ -234,21 +234,23 @@ class PrefillWorker:
                 and self._hits.get(batch[0].rid, (0,))[0]):
             self._run_suffix(batch[0])
             return
-        t0 = time.perf_counter()
-        toks = jnp.asarray([r.prompt for r in batch], jnp.int32)
-        extras = extra_inputs(self.cfg, len(batch))
-        # pin capacity_mode to what the inline engine's per-request
-        # (B=1) prefill would resolve "auto" to — batching must not flip
-        # a request from drop-free "full" into bounded "eval" capacity
-        # (models.prefill's auto threshold is B*T <= 2048), or parity
-        # with the inline path breaks for large chunk_tokens
-        capacity = "full" if toks.shape[1] <= 2048 else "eval"
-        last_logits, cache = self._prefill(self.params, self.cfg, toks,
-                                           self.max_seq,
-                                           capacity_mode=capacity, **extras)
-        greedy = jnp.argmax(last_logits, -1)
-        dt = time.perf_counter() - t0
-        self.t_prefill_s += dt
+        with self.obs.span("prefill.batch", size=len(batch),
+                           prompt_len=len(batch[0].prompt)) as sp:
+            toks = jnp.asarray([r.prompt for r in batch], jnp.int32)
+            extras = extra_inputs(self.cfg, len(batch))
+            # pin capacity_mode to what the inline engine's per-request
+            # (B=1) prefill would resolve "auto" to — batching must not
+            # flip a request from drop-free "full" into bounded "eval"
+            # capacity (models.prefill's auto threshold is B*T <= 2048),
+            # or parity with the inline path breaks for large
+            # chunk_tokens
+            capacity = "full" if toks.shape[1] <= 2048 else "eval"
+            last_logits, cache = self._prefill(self.params, self.cfg, toks,
+                                               self.max_seq,
+                                               capacity_mode=capacity,
+                                               **extras)
+            greedy = jnp.argmax(last_logits, -1)
+        dt = sp.seconds
         self.n_batches += 1
         for i, req in enumerate(batch):
             row = extract_row(cache, i)
@@ -274,7 +276,8 @@ class PrefillWorker:
 
     # -------------------------------------------------------------- metrics
     def stats(self) -> dict:
-        return {"prefill_s": self.t_prefill_s, "prefills": self.n_prefills,
+        return {"prefill_s": self.obs.seconds("prefill.batch"),
+                "prefills": self.n_prefills,
                 "prefill_batches": self.n_batches,
                 "prefill_tokens": self.n_tokens,
                 "prefill_devices": len(self.mesh.devices.flat)}

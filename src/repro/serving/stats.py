@@ -44,6 +44,15 @@ class PhaseStats(TypedDict, total=False):
     decode_n: int
 
 
+class CounterStats(TypedDict):
+    """Cumulative counts of the engine's work (``Engine.counters``)."""
+    host_syncs: int
+    decode_steps: int
+    admissions: int
+    programs_built: int
+    programs_from_cache: int
+
+
 class TransportHopStats(TypedDict):
     """One hop kind's cumulative counters (see ``core.transport``)."""
     hops: int
@@ -104,6 +113,7 @@ class EngineStats(TypedDict, total=False):
     disagg_prefill: bool
     kv_layout: str
     phases: PhaseStats
+    counters: CounterStats
     # paged KV layout only (schema v4+)
     kv_pages: PagePoolStats
     prefix_cache: PrefixCacheStats
