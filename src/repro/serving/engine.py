@@ -259,7 +259,7 @@ class Engine:
             lambda toks, cache, pos: decode_step(
                 self.params, cfg, toks, cache, pos,
                 use_kernels=base.use_kernels))
-        self._last_token = [0] * max_batch
+        self._last_token = np.zeros((max_batch,), np.int32)
         # logits (B, V) of the first and the latest decode iteration:
         # every runtime decodes the same first step from the same
         # prefill, so first_logits is what runtime-parity checks compare
@@ -559,27 +559,34 @@ class Engine:
         if not self.running:
             return 0
         with span("engine.prepare"):
-            toks = jnp.asarray(self._last_token, jnp.int32)
-            pos = jnp.zeros((self.max_batch,), jnp.int32)
+            # every per-row input is built on the host in one pass over
+            # the running rows and crosses to the device as one array
+            # each; free slots decode at position 0
+            paged = self.kv_layout == "paged"
+            pos = np.zeros((self.max_batch,), np.int32)
+            active = np.zeros((self.max_batch,), np.float32)
+            if paged:
+                bt = np.full((self.max_batch, self.page_pool.n_logical), -1,
+                             np.int32)
             for req in self.running.values():
-                pos = pos.at[req.slot].set(req.position - 1)
+                pos[req.slot] = req.position - 1
+                active[req.slot] = 1.0
+                if paged:
+                    tb = self.block_tables[req.rid]
+                    bt[req.slot, :len(tb)] = tb
+            # a copy: sampling rewrites _last_token while this step may
+            # still be in flight
+            toks = jnp.asarray(self._last_token.copy())
+            pos = jnp.asarray(pos)
             if self._track_experts:
                 # only live rows feed the routing-count traffic trace
-                active = np.zeros((self.max_batch,), np.float32)
-                for req in self.running.values():
-                    active[req.slot] = 1.0
                 self.runtime.set_active_slots(active)
-            if self.kv_layout == "paged":
+            if paged:
                 # block-table gather: materialize the dense (B, W) view
                 # the decode step expects.  The gather is a pure copy
                 # (unmapped pages read as pos=-1, exactly a reset row), so
                 # the decode computation below is bit-identical to the
                 # contiguous layout's across all runtimes and kernels.
-                bt = np.full((self.max_batch, self.page_pool.n_logical), -1,
-                             np.int32)
-                for req in self.running.values():
-                    tb = self.block_tables[req.rid]
-                    bt[req.slot, :len(tb)] = tb
                 cache = self.page_pool.gather(bt)
             else:
                 cache = self.cache
