@@ -1,10 +1,9 @@
 """Useful model flops of the traced steps over window x chips x peak (%).
 
-A decode token counts 2 flops per parameter it multiplies (attention
-projections, router, its top-k experts, the LM head) plus 4 * ctx * H * hd
-per layer; a prompt counts the same per token under causal attention and
-one LM head.  Expert capacity padding is not work the model needs."""
-from benchmarks.chip import flops
+A decode token and a prompt count the flops that the configuration's
+reference module says they need (``dims.decode_token_flops``,
+``dims.prefill_flops``).  Expert capacity padding is not work the model
+needs."""
 
 
 def read(rec, red):
@@ -13,8 +12,8 @@ def read(rec, red):
     d = rec["dims"]
     work = 0.0
     for s in rec["traced_steps"]:
-        work += sum(flops.prefill_flops(d, P) for P in s.prefill)
-        work += sum(flops.decode_token_flops(d, c) for c in s.decode_ctx)
+        work += sum(d.prefill_flops(P) for P in s.prefill)
+        work += sum(d.decode_token_flops(c) for c in s.decode_ctx)
     if work <= 0:
         return None
     return 100.0 * work / (red.window_s * rec["chips"]

@@ -17,8 +17,10 @@ returns, at which the client sees it.
 a few seconds of the window with the profiler and reports the per-layer
 metrics, the device's busy time and a breakdown.  Either way, once the
 window has closed and the program's state is freed, a float32 reference
-(``reference.py``) recomputes a sample of the served requests and decides
-``correct``.  With no TPU, or fewer chips than the cell asks for, the run
+recomputes a sample of the served requests and decides ``correct``: the
+module ``references/<name>.py`` that the configuration file names under
+``"reference"``, which also gives the sizes and the work counts the
+readers take.  With no TPU, or fewer chips than the cell asks for, the run
 fails and prints no result.
 """
 from __future__ import annotations
@@ -50,8 +52,7 @@ CACHE_HIT = "/jax/compilation_cache/cache_hits"
 TRACE_DIR = ROOT / ".bench_trace"
 
 
-class BenchError(RuntimeError):
-    """The run cannot give a result (no chip, a wrong configuration)."""
+BenchError = spec.BenchError
 
 
 def log(msg: str):
@@ -106,18 +107,13 @@ class CompileCounter:
             self.loads += 1
 
 
-def check_program_config(model_cfg, dims):
-    """The program must serve the sizes the configuration file states."""
-    have = {"d_model": model_cfg.d_model, "n_heads": model_cfg.n_heads,
-            "n_kv_heads": model_cfg.n_kv_heads,
-            "head_dim": model_cfg.resolved_head_dim,
-            "n_experts": model_cfg.moe.n_experts,
-            "top_k": model_cfg.moe.top_k,
-            "d_ff_expert": model_cfg.moe.d_ff_expert,
-            "vocab": model_cfg.vocab, "n_layers": model_cfg.n_layers,
-            "rope_theta": model_cfg.rope_theta}
-    bad = {k: (v, getattr(dims, k)) for k, v in have.items()
-           if v != getattr(dims, k)}
+def check_program_config(model_cfg, ref, dims):
+    """The program must serve the sizes the configuration file states, as
+    the reference module ``ref`` reads both."""
+    have, want = ref.program_sizes(model_cfg), ref.sizes(dims)
+    bad = {k: (have.get(k), want.get(k))
+           for k in sorted(have.keys() | want.keys())
+           if have.get(k) != want.get(k)}
     if bad:
         raise BenchError(f"the program serves other sizes than the "
                          f"configuration file (program, file): {bad}")
@@ -290,7 +286,7 @@ def pick_sample(served: List[Sent], seed: int, want_tokens: int,
     return out
 
 
-def compare(dims, seed: int, sample, max_seq: int, tie: float,
+def compare(ref, dims, seed: int, sample, max_seq: int, tie: float,
             control: bool, dtype: str) -> dict:
     """Widest gap by which a served token's logit lies below the
     reference's best logit, over the served tokens of the sample whose
@@ -300,8 +296,8 @@ def compare(dims, seed: int, sample, max_seq: int, tie: float,
     pass's first choices."""
     import jax.numpy as jnp
     import numpy as np
-    from benchmarks.chip import reference
-    w = reference.init_weights(dims, program_seed(seed), dtype)
+    from benchmarks.chip.reference import served_gaps
+    w = ref.init_weights(dims, program_seed(seed), dtype)
     gaps, lows, margins = [], [], []
     for prompt, gen in sample:
         seq = list(prompt) + list(gen)
@@ -313,8 +309,8 @@ def compare(dims, seed: int, sample, max_seq: int, tie: float,
         toks[:len(seq) - 1] = seq[:-1]
         tgt = np.zeros((max_seq,), np.int32)
         tgt[:len(seq) - 1] = seq[1:]
-        gap, low, margin = reference.served_gaps(
-            w, dims, jnp.asarray(toks), jnp.asarray(tgt), control)
+        gap, low, margin = served_gaps(
+            ref.forward, w, dims, jnp.asarray(toks), jnp.asarray(tgt), control)
         served = slice(P - 1, P - 1 + G)
         gaps.append(np.asarray(gap[served]))
         margins.append(np.asarray(margin[served]))
@@ -366,7 +362,6 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     """Set up, drive, measure and check one cell; return the result line.
     ``platform`` is the JAX platform the run insists on."""
     import jax
-    from benchmarks.chip import reference
     from benchmarks.chip.peaks import peaks_for
 
     cell = spec.cell(bench, cell_name)
@@ -383,13 +378,14 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     devices = devices[:chips]
     kind = devices[0].device_kind
     peaks = peaks_for(kind) if platform == "tpu" else None
-    dims = reference.dims_of(config)
+    ref = spec.reference(config)
+    dims = ref.dims_of(config)
     log(f"cache: {enable_compile_cache(jax)}")
     counter = CompileCounter(jax)
 
     ta = time.perf_counter()
     eng = build_engine(cell, config, seed)
-    check_program_config(eng.cfg, dims)
+    check_program_config(eng.cfg, ref, dims)
     add_spans(eng)
     clients = int(cell["serving"]["max_batch"])
     traffic = generator.Traffic(mix, seed, dims.vocab, clients)
@@ -483,7 +479,7 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     log(f"device bytes still live after freeing the program: {live}")
     limit = chk["limit"]["logit_gap"]
     tr = time.perf_counter()
-    got = compare(dims, seed, sample, int(cell["serving"]["max_seq"]),
+    got = compare(ref, dims, seed, sample, int(cell["serving"]["max_seq"]),
                   chk["router_tie"], control, config["program"]["dtype"])
     log(f"reference: {time.perf_counter() - tr:.3f} s for {got['tokens']} "
         f"served tokens of {len(sample)} requests, {got['near_ties']} of "

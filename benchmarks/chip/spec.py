@@ -1,7 +1,9 @@
-"""Find a cell, its configuration, its traffic mix and its metric readers
-by the names ``BENCHMARK.json`` gives them."""
+"""Find a cell, its configuration, its traffic mix, its reference module
+and its metric readers by the names ``BENCHMARK.json`` and the
+configuration file give them."""
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 from pathlib import Path
@@ -9,6 +11,10 @@ from typing import Callable, List
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+
+
+class BenchError(RuntimeError):
+    """The run cannot give a result (no chip, a wrong configuration)."""
 
 
 def _json(path: Path) -> dict:
@@ -35,6 +41,19 @@ def config(bench: dict, name: str, root: Path = ROOT) -> dict:
         if c["name"] == name:
             return _json(root / c["file"])
     raise KeyError(f"no configuration {name!r} in BENCHMARK.json")
+
+
+def reference(config: dict):
+    """``references/<name>.py`` for the configuration's ``"reference"``:
+    its sizes, weights, forward pass and work counts
+    (``references/__init__.py``)."""
+    name = config["reference"]
+    have = sorted(p.stem for p in (HERE / "references").glob("*.py")
+                  if not p.stem.startswith("_"))
+    if name not in have:
+        raise BenchError(f"the configuration names reference {name!r}; "
+                         f"references/ holds {have}")
+    return importlib.import_module(f"benchmarks.chip.references.{name}")
 
 
 def traffic(name: str) -> dict:

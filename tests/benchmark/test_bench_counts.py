@@ -1,12 +1,20 @@
 """The benchmark's own operation and byte counts, against values worked
-out by hand at mixtral-8x22b and DBRX widths, and its peaks table."""
+out by hand at mixtral-8x22b and DBRX widths, and its peaks table.  The
+counts come from the reference module each configuration file names."""
 import pytest
 
 from benchmarks.chip import flops, peaks, reference, spec
 
 BENCH = spec.load_benchmark()
-MIXTRAL = reference.dims_of(spec.config(BENCH, "mixtral-8x22b"))
-DBRX = reference.dims_of(spec.config(BENCH, "dbrx"))
+
+
+def _dims(name):
+    config = spec.config(BENCH, name)
+    return spec.reference(config).dims_of(config)
+
+
+MIXTRAL = _dims("mixtral-8x22b")
+DBRX = _dims("dbrx")
 
 
 def test_configs_keep_the_published_widths():
@@ -27,36 +35,38 @@ def test_departures_give_what_the_program_serves():
     assert (MIXTRAL.rope_theta, MIXTRAL.rms_norm_eps) == (1e4, 1e-6)
     assert dbrx["attn_config"]["rope_theta"] == 5e5
     assert (DBRX.rope_theta, DBRX.rms_norm_eps) == (1e4, 1e-6)
-    # only depth is cut
-    assert [c["reduced"] for c in BENCH["configs"]] == [
-        ["num_hidden_layers"], ["n_layers"]]
+    # only depth is cut, and one reference module serves both
+    reduced = {c["name"]: c["reduced"] for c in BENCH["configs"]}
+    assert reduced["mixtral-8x22b"] == ["num_hidden_layers"]
+    assert reduced["dbrx"] == ["n_layers"]
+    assert mix["reference"] == dbrx["reference"] == "moe_gqa"
     assert reference.served({}, "rope_theta", 3.0) == 3.0
 
 
 def test_layer_params():
     # attention 6144*128*(2*48 + 2*8) = 88,080,384; router 6144*8;
     # two experts of 3*6144*16384
-    assert flops.layer_matmul_params(MIXTRAL) == (
+    assert MIXTRAL.layer_matmul_params() == (
         88_080_384 + 49_152 + 603_979_776)
     # router 6144*16; four experts of 3*6144*10752
-    assert flops.layer_matmul_params(DBRX) == (
+    assert DBRX.layer_matmul_params() == (
         88_080_384 + 98_304 + 792_723_456)
 
 
 def test_decode_token_flops():
     # 2 * (692,109,312 + 6144*32000) + 4 * 1000 * 48 * 128
-    assert flops.decode_token_flops(MIXTRAL, 1000) == 1_802_010_624
+    assert MIXTRAL.decode_token_flops(1000) == 1_802_010_624
     # 2 * (880,902,144 + 6144*100352) + 4 * 10 * 48 * 128
-    assert flops.decode_token_flops(DBRX, 10) == 2_995_175_424
+    assert DBRX.decode_token_flops(10) == 2_995_175_424
 
 
 def test_prefill_flops():
     # 2*128*692,109,312 + 4*(128*129/2)*48*128 + 2*6144*32000
-    assert flops.prefill_flops(MIXTRAL, 128) == 177_776_099_328
+    assert MIXTRAL.prefill_flops(128) == 177_776_099_328
 
 
 def test_decode_attention_cost():
-    f, b = flops.decode_attention_cost(MIXTRAL, [100, 300])
+    f, b = MIXTRAL.decode_attention_cost([100, 300])
     assert f == 4 * 400 * 48 * 128 == 9_830_400
     # q and out: 2 rows * 48 * 128 * 2 B each; K and V: 400 * 8 * 128 * 2 B
     assert b == 2 * 2 * 48 * 128 * 2 + 2 * 400 * 8 * 128 * 2 == 1_687_552

@@ -12,7 +12,7 @@ from benchmarks.chip import program, spec, trace, trace_program
 from benchmarks.chip.program import ProgramIntervals, ProgramReduced, ScopedOp
 from benchmarks.chip.run import StepRecord
 from benchmarks.chip.trace import Intervals, Reduced, Span
-from test_bench_run import CELL, cache_dir, toy  # noqa: F401 (fixtures)
+from test_bench_run import cache_dir, toy  # noqa: F401 (fixtures)
 
 TESTDATA = Path(__file__).resolve().parents[2] / "benchmarks" / "chip" / \
     "testdata"
@@ -139,8 +139,9 @@ def test_readers_are_silent_without_the_program_record(monkeypatch):
 
 def test_traced_toy_run_reads_the_program(toy):
     """A traced run of the toy cell on the CPU: the five metrics are read,
-    the engine's syncs are one per token the client saw, and the profiler
-    trace holds the engine's spans on the window's clock."""
+    each per-step count is the engine's own counter over the traced decode
+    steps, and the profiler trace holds the engine's spans on the window's
+    clock."""
     seen = {}
     reader = spec.reader
 
@@ -160,11 +161,14 @@ def test_traced_toy_run_reads_the_program(toy):
     assert m["prepare_ms"] > 0 and m["sample_ms"] > 0 \
         and m["decode_call_ms"] > 0
     steps = seen["steps"]
-    tokens = sum(len(s.prefill) + len(s.decode_ctx) for s in steps)
-    assert m["host_syncs_per_step"] == pytest.approx(tokens / len(steps))
-    assert m["host_syncs_per_step"] >= CELL["serving"]["max_batch"]
     c = out["counters"]
     assert c["decode_steps"] == len(steps)
+    assert m["host_syncs_per_step"] == pytest.approx(
+        c["host_syncs"] / len(steps))
+    # at least one read a step, and no more than one a token the client
+    # saw: a batched read passes, a counter that stopped counting does not
+    tokens = sum(len(s.prefill) + len(s.decode_ctx) for s in steps)
+    assert 1 <= m["host_syncs_per_step"] <= tokens / len(steps)
     assert m["programs_built_per_step"] == pytest.approx(
         c.get("programs_built", 0) / len(steps))
     idle = dict(out["breakdown"]["idle_by_span"])

@@ -1,13 +1,15 @@
-"""The benchmark's float32 reference and its weights, against the program
-at a toy size on the CPU."""
+"""The benchmark's float32 reference of the mixtral and DBRX family
+(``references/moe_gqa.py``) and its weights, against the program at a toy
+size on the CPU, and the numerics every family shares
+(``reference.py``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.chip import reference
-from benchmarks.chip.flops import Dims
+from benchmarks.chip.references import moe_gqa
 
-TOY = Dims(d_model=256, n_heads=4, n_kv_heads=1, head_dim=64, n_experts=4,
+TOY = moe_gqa.Dims(d_model=256, n_heads=4, n_kv_heads=1, head_dim=64, n_experts=4,
            top_k=2, d_ff_expert=128, vocab=512, n_layers=2)
 
 
@@ -25,7 +27,7 @@ def test_weights_are_the_launchers_draw():
     assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
             cfg.moe.n_experts, cfg.moe.top_k, cfg.moe.d_ff_expert,
             cfg.vocab, cfg.n_layers) == (256, 4, 1, 64, 4, 2, 128, 512, 2)
-    w = reference.init_weights(TOY, seed, jnp.bfloat16)
+    w = moe_gqa.init_weights(TOY, seed, jnp.bfloat16)
     for a, b in [(w["embed"], p["embed"]), (w["lm_head"], p["lm_head"]),
                  (w["final_norm"], p["final_norm"])]:
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -43,9 +45,9 @@ def test_reference_matches_the_programs_forward_pass():
     from repro.models import prefill
     seed = 7
     cfg, p = _program_weights(seed, jnp.float32)
-    w = reference.init_weights(TOY, seed, jnp.float32)
+    w = moe_gqa.init_weights(TOY, seed, jnp.float32)
     toks = np.random.default_rng(0).integers(0, 512, 24).astype(np.int32)
-    ref = np.asarray(reference.logits(w, TOY, jnp.asarray(toks)))
+    ref = np.asarray(moe_gqa.forward(w, TOY, jnp.asarray(toks))[0])
     for n in (1, 9, 24):
         with jax.default_matmul_precision("highest"):
             last, _ = prefill(p, cfg, jnp.asarray(toks[None, :n]), max_seq=32)
@@ -54,17 +56,17 @@ def test_reference_matches_the_programs_forward_pass():
 
 
 def test_served_gaps_are_zero_for_the_references_own_choices():
-    w = reference.init_weights(TOY, 5, jnp.float32)
+    w = moe_gqa.init_weights(TOY, 5, jnp.float32)
     toks = jnp.asarray(np.random.default_rng(1).integers(0, 512, 16),
                        jnp.int32)
-    ref = reference.logits(w, TOY, toks)
+    ref = moe_gqa.forward(w, TOY, toks)[0]
     best = jnp.argmax(ref, axis=-1).astype(jnp.int32)
-    gap, low, margin = reference.served_gaps(w, TOY, toks, best, True)
+    gap, low, margin = reference.served_gaps(moe_gqa.forward, w, TOY, toks, best, True)
     assert float(jnp.max(gap)) == 0.0
     assert float(jnp.max(low)) >= 0.0
     assert margin.shape == (16,) and float(jnp.min(margin)) >= 0.0
     worst = jnp.argmin(ref, axis=-1).astype(jnp.int32)
-    gap, _, _ = reference.served_gaps(w, TOY, toks, worst, False)
+    gap, _, _ = reference.served_gaps(moe_gqa.forward, w, TOY, toks, worst, False)
     np.testing.assert_allclose(np.asarray(gap),
                                np.asarray(ref.max(-1) - ref.min(-1)),
                                rtol=1e-6)

@@ -12,16 +12,18 @@ every served request: a toy window holds few, and a fault that spares
 half of the batch's rows must meet a sampled request in the other half.
 """
 import time
+import types
 
 import numpy as np
 import pytest
 
-from benchmarks.chip import run, spec
+from benchmarks.chip import peaks, run, spec
+from benchmarks.chip.references import moe_gqa
 
 TOY = {"num_hidden_layers": 2, "hidden_size": 256, "intermediate_size": 128,
        "num_attention_heads": 4, "num_key_value_heads": 1, "head_dim": 64,
        "num_local_experts": 4, "num_experts_per_tok": 2, "vocab_size": 512,
-       "rms_norm_eps": 1e-6, "rope_theta": 10000.0,
+       "rms_norm_eps": 1e-6, "rope_theta": 10000.0, "reference": "moe_gqa",
        "program": {"arch": "mixtral-8x22b", "use_reduced": True,
                    "n_layers": 0, "dtype": "bfloat16"}}
 MIX = {"block": 16, "blocks": 4, "prompt_classes": {"8": 0.5, "16": 0.5},
@@ -166,3 +168,94 @@ def test_open_loop_cell_reports_time_to_first_token(toy):
     assert out["correct"], out["checks"]
     assert out["metrics"]["ttft_p95_ms"]["value"] > 0
     assert 5 <= out["attempted"] <= 40
+
+
+def _recording(seen: list):
+    """A reference module of a family of its own: ``moe_gqa``'s
+    arithmetic, with every call into it noted in ``seen``."""
+    class Dims(moe_gqa.Dims):
+        def decode_token_flops(self, ctx):
+            seen.append("decode_token_flops")
+            return super().decode_token_flops(ctx)
+
+        def prefill_flops(self, prompt_len):
+            seen.append("prefill_flops")
+            return super().prefill_flops(prompt_len)
+
+    def noted(name, fn):
+        def call(*a, **k):
+            seen.append(name)
+            return fn(*a, **k)
+        return call
+
+    def dims_of(config):
+        seen.append("dims_of")
+        return Dims(**vars(moe_gqa.dims_of(config)))
+
+    return types.SimpleNamespace(
+        Dims=Dims, dims_of=dims_of,
+        forward=noted("forward", moe_gqa.forward),
+        program_sizes=noted("program_sizes", moe_gqa.program_sizes),
+        sizes=noted("sizes", moe_gqa.sizes),
+        init_weights=noted("init_weights", moe_gqa.init_weights))
+
+
+def test_the_run_takes_the_model_from_the_named_reference(toy):
+    """A configuration that names another reference module gets every
+    model-shaped part of a run from it: the sizes, the program check, the
+    weights, the served tokens' gaps and the work the ``mfu`` reader
+    counts.  So a family of another shape needs new files only."""
+    seen, records = [], []
+    module = _recording(seen)
+    load = spec.reference
+    toy.setattr(spec, "config",
+                lambda bench, name: dict(TOY, reference="recording"))
+    toy.setattr(spec, "reference", lambda config: module
+                if config["reference"] == "recording" else load(config))
+    reader = spec.reader
+
+    def with_peaks(name):
+        # the CPU has no peaks; the counts are what this test reads
+        fn = reader(name)
+
+        def read(rec, red):
+            records.append((rec, red))
+            return fn({**rec, "peaks": peaks.peaks_for("TPU v5 lite")}, red)
+        return read
+    toy.setattr(spec, "reader", with_peaks)
+    out = _run(trace=True)
+    assert out["correct"], out["checks"]
+    assert all(type(rec["dims"]) is module.Dims for rec, _ in records)
+    for name in ("dims_of", "program_sizes", "sizes", "init_weights",
+                 "forward", "decode_token_flops"):
+        assert name in seen, name
+    rec, red = records[0]
+    d = moe_gqa.dims_of(TOY)
+    work = sum(d.prefill_flops(P) + 0.0 for s in rec["traced_steps"]
+               for P in s.prefill) + sum(
+        d.decode_token_flops(c) for s in rec["traced_steps"]
+        for c in s.decode_ctx)
+    assert work > 0
+    assert out["metrics"]["mfu"]["value"] == pytest.approx(
+        100.0 * work / (red.window_s * 197e12))
+
+
+@pytest.mark.parametrize("name", ["mixtral", "../reference"])
+def test_an_unknown_reference_lists_the_modules_present(name):
+    with pytest.raises(run.BenchError) as err:
+        spec.reference({"reference": name})
+    named, listed = str(err.value).split("references/ holds ")
+    assert repr(name) in named
+    present = [p.stem for p in (spec.HERE / "references").glob("*.py")
+               if not p.stem.startswith("_")]
+    assert "moe_gqa" in present
+    assert all(repr(m) in listed for m in present)
+
+
+def test_the_program_check_compares_the_modules_two_readings():
+    ref = types.SimpleNamespace(program_sizes=lambda cfg: {"a": 1, "b": 2},
+                                sizes=lambda d: {"a": 1, "b": 3})
+    with pytest.raises(run.BenchError, match=r"'b': \(2, 3\)"):
+        run.check_program_config(None, ref, None)
+    ref.sizes = lambda d: {"a": 1, "b": 2}
+    run.check_program_config(None, ref, None)
