@@ -11,11 +11,14 @@ Layer kinds (one token-mixing module + one FFN per layer, except noted):
   "selfcross" self-attention + cross-attention + FFN  (whisper decoder)
   "rglru"     RG-LRU recurrent block + FFN            (recurrentgemma)
   "ssd"       Mamba-2 SSD block (no separate FFN)
+  "mla"       multi-head latent attention (DeepSeek-V2/V3) + FFN
+  "mla_dense" multi-head latent attention + a dense FFN of ``d_ff``, in
+              a model whose other layers are MoE
 
-A model's layer stack is ``block_pattern`` repeated ``n_blocks`` times
-followed by ``remainder_pattern``; the repeated part is executed with
-``jax.lax.scan`` over stacked parameters so HLO size (and compile time)
-is O(len(block_pattern)), not O(n_layers).
+A model's layer stack is ``lead_pattern``, then ``block_pattern``
+repeated ``n_blocks`` times, then ``remainder_pattern``; the repeated
+part is executed with ``jax.lax.scan`` over stacked parameters so HLO
+size (and compile time) is O(len(block_pattern)), not O(n_layers).
 """
 from __future__ import annotations
 
@@ -23,7 +26,11 @@ import dataclasses
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
-LAYER_KINDS = ("attn", "local", "cross", "selfcross", "rglru", "ssd")
+LAYER_KINDS = ("attn", "local", "cross", "selfcross", "rglru", "ssd",
+               "mla", "mla_dense")
+MLA_KINDS = ("mla", "mla_dense")
+# layer kinds whose FFN is the model's MoE when it has one
+_MOE_FFN_KINDS = ("attn", "local", "cross", "selfcross", "rglru", "mla")
 
 
 @dataclass(frozen=True)
@@ -41,6 +48,79 @@ class MoEConfig:
     # token group size for GShard-style einsum dispatch (memory control)
     group_size: int = 2048
     router_aux_coef: float = 0.01
+    # the shared expert's output is scaled by a learned sigmoid gate
+    # (qwen2-moe); DeepSeek's shared expert is added as it is
+    shared_gated: bool = True
+    # "softmax": top-k of the softmax, renormalised.  "sigmoid"
+    # (DeepSeek-V3 noaux_tc): sigmoid scores; experts are chosen on the
+    # scores plus a per-expert correction bias, inside the ``topk_group``
+    # of ``n_group`` groups whose two best biased scores sum highest;
+    # the chosen experts' unbiased scores, normalised to sum 1, times
+    # ``routed_scaling`` weight them
+    scoring: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+    # expert parallelism's share: the layer holds ``n_held`` of the
+    # router's ``n_experts`` routed experts, ``first_held`` onwards, and
+    # computes their part of the result (0 = every expert is held)
+    n_held: int = 0
+    first_held: int = 0
+
+    def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router scoring {self.scoring!r}")
+        if self.n_experts % self.n_group or not (
+                1 <= self.topk_group <= self.n_group):
+            raise ValueError(f"{self.n_experts} experts in {self.n_group} "
+                             f"groups, {self.topk_group} kept")
+        if not (0 <= self.first_held
+                and self.first_held + self.held <= self.n_experts):
+            raise ValueError(f"experts {self.first_held}.."
+                             f"{self.first_held + self.held - 1} held of "
+                             f"{self.n_experts}")
+
+    @property
+    def held(self) -> int:
+        """Routed experts this layer holds and computes."""
+        return self.n_held or self.n_experts
+
+    @property
+    def holds_all(self) -> bool:
+        return self.held == self.n_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3).  Queries go through a
+    ``q_lora_rank`` bottleneck; keys and values are expanded per head from
+    one ``kv_lora_rank`` latent, and a ``qk_rope_head_dim`` rotary key is
+    shared by all heads.  The decode cache holds the latent and the
+    rotary key: ``kv_lora_rank + qk_rope_head_dim`` values a position."""
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (``rope_scaling`` of type "yarn")."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -89,6 +169,7 @@ class ModelConfig:
     head_dim: Optional[int] = None      # default: d_model // n_heads
     block_pattern: Tuple[str, ...] = ("attn",)
     remainder_pattern: Tuple[str, ...] = ()
+    lead_pattern: Tuple[str, ...] = ()  # unscanned layers ahead of the blocks
     window: int = 4096                  # sliding window for "local"
     attn_softcap: float = 0.0           # gemma2
     logit_softcap: float = 0.0          # gemma2
@@ -97,6 +178,8 @@ class ModelConfig:
     rope_theta: float = 10000.0
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[YarnConfig] = None
     ssm: Optional[SSMConfig] = None
     rglru: Optional[RGLRUConfig] = None
     encoder: Optional[EncoderConfig] = None
@@ -107,19 +190,36 @@ class ModelConfig:
     source: str = ""                    # citation for the config
 
     def __post_init__(self):
-        n_rem = len(self.remainder_pattern)
+        n_rem = len(self.remainder_pattern) + len(self.lead_pattern)
         n_pat = len(self.block_pattern)
-        if (self.n_layers - n_rem) % n_pat != 0:
+        if self.n_layers < n_rem or (self.n_layers - n_rem) % n_pat != 0:
             raise ValueError(
                 f"{self.name}: n_layers={self.n_layers} incompatible with "
-                f"pattern of {n_pat} + remainder of {n_rem}")
-        for k in self.block_pattern + self.remainder_pattern:
+                f"pattern of {n_pat} + lead and remainder of {n_rem}")
+        for k in self.layer_kinds:
             if k not in LAYER_KINDS:
                 raise ValueError(f"unknown layer kind {k!r}")
+            if k in MLA_KINDS and self.mla is None:
+                raise ValueError(f"{self.name}: layer kind {k!r} needs an "
+                                 f"MLAConfig")
 
     @property
     def n_blocks(self) -> int:
-        return (self.n_layers - len(self.remainder_pattern)) // len(self.block_pattern)
+        return ((self.n_layers - len(self.remainder_pattern)
+                 - len(self.lead_pattern)) // len(self.block_pattern))
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The kind of each layer, first to last."""
+        return (self.lead_pattern + self.block_pattern * self.n_blocks
+                + self.remainder_pattern)
+
+    @property
+    def n_moe_layers(self) -> int:
+        """Layers whose FFN is the MoE."""
+        if self.moe is None:
+            return 0
+        return sum(k in _MOE_FFN_KINDS for k in self.layer_kinds)
 
     @property
     def resolved_head_dim(self) -> int:
@@ -127,19 +227,18 @@ class ModelConfig:
 
     @property
     def has_cross(self) -> bool:
-        kinds = self.block_pattern + self.remainder_pattern
-        return any(k in ("cross", "selfcross") for k in kinds)
+        return any(k in ("cross", "selfcross") for k in self.layer_kinds)
 
     def param_count(self) -> int:
         """Analytic parameter count (embeddings + all layers)."""
         d, hd = self.d_model, self.resolved_head_dim
         qkv = d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + self.n_heads * hd * d
 
-        def ffn_params() -> int:
-            if self.moe is not None:
+        def ffn_params(dense: bool = False) -> int:
+            if self.moe is not None and not dense:
                 m = self.moe
                 p = d * m.n_experts  # router
-                p += m.n_experts * 3 * d * m.d_ff_expert
+                p += m.held * 3 * d * m.d_ff_expert
                 if m.n_shared_experts:
                     p += 3 * d * m.d_ff_shared
                 if m.d_ff_dense_residual:
@@ -147,7 +246,16 @@ class ModelConfig:
                 return p
             return 3 * d * self.d_ff
 
+        def mla_params() -> int:
+            a, H = self.mla, self.n_heads
+            return (d * a.q_lora_rank + a.q_lora_rank * H * a.qk_head_dim
+                    + d * a.latent_dim
+                    + a.kv_lora_rank * H * (a.qk_nope_head_dim + a.v_head_dim)
+                    + H * a.v_head_dim * d + a.q_lora_rank + a.kv_lora_rank)
+
         def layer_params(kind: str) -> int:
+            if kind in MLA_KINDS:
+                return mla_params() + ffn_params(kind == "mla_dense") + 2 * d
             if kind in ("attn", "local"):
                 return qkv + ffn_params() + 2 * d
             if kind == "cross":
@@ -174,7 +282,7 @@ class ModelConfig:
         total += d  # final norm
         for k in self.block_pattern:
             total += layer_params(k) * self.n_blocks
-        for k in self.remainder_pattern:
+        for k in self.lead_pattern + self.remainder_pattern:
             total += layer_params(k)
         if self.encoder is not None:
             enc_layer = 2 * qkv // 2 + 3 * d * self.d_ff // 3 * 0  # placeholder
@@ -233,12 +341,40 @@ def _load_all():
     from repro import configs as _  # noqa: F401
 
 
+def chip_share(cfg: ModelConfig, *, n_layers: int = 0, lead_layers: int = 0,
+               experts_held: int = 0, vocab: int = 0) -> ModelConfig:
+    """One chip's share of a model, every width kept.
+
+    ``lead_layers`` > 0 keeps that many of the leading layers and
+    ``n_layers`` > 0 cuts the whole stack to that many (the leading
+    layers among them): the layers left out would be further pipeline
+    stages.  ``experts_held`` > 0 holds that many routed experts of each
+    MoE layer, the first of them; the router keeps every output.
+    ``vocab`` > 0 serves that slice of the vocabulary.  0 keeps what the
+    configuration has."""
+    kw = {}
+    if lead_layers:
+        kw.update(lead_pattern=cfg.lead_pattern[:lead_layers],
+                  n_layers=cfg.n_layers - len(cfg.lead_pattern) + lead_layers)
+    if n_layers:
+        kw["n_layers"] = n_layers
+    if experts_held:
+        if cfg.moe is None:
+            raise ValueError(f"{cfg.name} has no routed experts to hold")
+        kw["moe"] = dataclasses.replace(cfg.moe, n_held=experts_held,
+                                        first_held=0)
+    if vocab:
+        kw["vocab"] = vocab
+    return dataclasses.replace(cfg, **kw) if kw else cfg
+
+
 def reduced(cfg: ModelConfig, *, n_layers: int = 2, d_model: int = 256,
             n_heads: int = 4, vocab: int = 512) -> ModelConfig:
     """A tiny same-family variant for CPU smoke tests.
 
     Keeps the layer-kind pattern, MoE-ness, softcaps etc., shrinks dims:
-    <=2 effective blocks, d_model<=512, <=4 experts.
+    <=2 effective blocks after at most one leading layer, d_model<=512,
+    <=4 experts (16 in 4 groups under group-limited sigmoid routing).
     """
     hd = 64
     n_kv = max(1, min(cfg.n_kv_heads, n_heads) // max(1, cfg.n_heads // max(cfg.n_kv_heads, 1) and 1))
@@ -249,7 +385,16 @@ def reduced(cfg: ModelConfig, *, n_layers: int = 2, d_model: int = 256,
     rem = ()
     layers = len(pat) * max(1, n_layers // len(pat)) if len(pat) <= n_layers else len(pat)
     moe = None
-    if cfg.moe is not None:
+    if cfg.moe is not None and cfg.moe.scoring == "sigmoid":
+        # group-limited routing needs groups to choose among
+        moe = dataclasses.replace(
+            cfg.moe, n_experts=16, top_k=min(cfg.moe.top_k, 4),
+            n_group=min(cfg.moe.n_group, 4),
+            topk_group=min(cfg.moe.topk_group, 2), d_ff_expert=128,
+            d_ff_shared=128 if cfg.moe.n_shared_experts else 0,
+            n_shared_experts=min(cfg.moe.n_shared_experts, 1),
+            n_held=0, first_held=0, group_size=64)
+    elif cfg.moe is not None:
         moe = dataclasses.replace(
             cfg.moe, n_experts=4, top_k=min(cfg.moe.top_k, 2), d_ff_expert=128,
             d_ff_shared=128 if cfg.moe.n_shared_experts else 0,
@@ -259,8 +404,12 @@ def reduced(cfg: ModelConfig, *, n_layers: int = 2, d_model: int = 256,
     ssm = dataclasses.replace(cfg.ssm, d_state=16, head_dim=32, chunk=16) if cfg.ssm else None
     rgl = dataclasses.replace(cfg.rglru, lru_width=d_model) if cfg.rglru else None
     enc = dataclasses.replace(cfg.encoder, n_layers=2, source_len=32) if cfg.encoder else None
+    mla = MLAConfig(q_lora_rank=64, kv_lora_rank=64, qk_nope_head_dim=32,
+                    qk_rope_head_dim=16, v_head_dim=32) if cfg.mla else None
+    lead = cfg.lead_pattern[:1]
     return dataclasses.replace(
-        cfg, name=cfg.name + "-smoke", n_layers=layers, d_model=d_model,
+        cfg, name=cfg.name + "-smoke", n_layers=len(lead) + layers,
+        lead_pattern=lead, mla=mla, d_model=d_model,
         n_heads=n_heads, n_kv_heads=n_kv, head_dim=hd,
         d_ff=4 * d_model if cfg.d_ff else 0, vocab=vocab,
         block_pattern=pat, remainder_pattern=rem, window=min(cfg.window, 16),

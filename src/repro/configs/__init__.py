@@ -1,5 +1,6 @@
 """Importing this package registers every architecture config."""
-from repro.configs import (arctic_480b, gemma2_27b, granite_3_8b,  # noqa: F401
+from repro.configs import (arctic_480b, deepseek_v3,  # noqa: F401
+                           gemma2_27b, granite_3_8b,
                            llama_3_2_vision_90b, mamba2_13b, minitron_4b,
                            minitron_8b, paper_models, qwen2_moe_a27b,
                            recurrentgemma_2b, whisper_large_v3)

@@ -47,7 +47,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
-from repro.config import ModelConfig
+from repro.config import MLA_KINDS, ModelConfig
 from repro.core import load_balance as lb_lib
 from repro.core import m2n as m2n_lib
 from repro.core import pingpong
@@ -152,12 +152,19 @@ class DisaggregatedInstance:
         plan = plan if plan is not None else DisaggPlan()
         self.transport = (transport if transport is not None
                           else transport_lib.InProcessTransport())
-        for kind in cfg.block_pattern + cfg.remainder_pattern:
+        for kind in cfg.layer_kinds:
+            if kind in MLA_KINDS:
+                raise NotImplementedError(
+                    f"the disaggregated runtime does not serve multi-head "
+                    f"latent attention (layer kind {kind!r}, {cfg.name}) "
+                    f"yet; use the monolithic engine")
             if kind not in ("attn", "local"):
                 raise NotImplementedError(
                     f"disaggregated runtime does not support layer kind "
                     f"{kind!r} ({cfg.name}); use the monolithic engine "
                     f"(see DESIGN.md §Arch-applicability)")
+        if cfg.moe is not None:
+            moe_lib.require_plain_routing(cfg.moe, "the disaggregated runtime")
         devs = list(devices) if devices is not None else jax.devices()
         attn_devices = list(attn_devices or devs[: max(1, len(devs) // 2)])
         expert_devices = list(expert_devices or devs[max(1, len(devs) // 2):]

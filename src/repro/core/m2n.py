@@ -97,6 +97,7 @@ def sharded_routed_experts(params: dict, x: jax.Array, cfg: MoEConfig,
     instead of the contiguous-block default, and the output stays
     token-identical to the unreplicated dispatch.
     """
+    moe_lib.require_plain_routing(cfg, "the M2N dispatch")
     n_shards = mesh.shape[expert_axis]
     E = cfg.n_experts
     if use_kernels and weights_2d:

@@ -8,7 +8,8 @@ rather than deep-importing the per-kernel modules.
 """
 from repro.kernels.ops import (decode_attention, gating_dispatch,
                                gating_topk, grouped_matmul, grouped_mlp,
-                               paged_decode_attention)
+                               mla_decode_attention, paged_decode_attention)
 
 __all__ = ["decode_attention", "gating_dispatch", "gating_topk",
-           "grouped_matmul", "grouped_mlp", "paged_decode_attention"]
+           "grouped_matmul", "grouped_mlp", "mla_decode_attention",
+           "paged_decode_attention"]

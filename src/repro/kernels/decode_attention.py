@@ -14,6 +14,10 @@ kv-heads of a block in a static loop, each with its (rep, hd) f32
 accumulator and (rep, 1) running max/denominator kept in scratch across
 KV blocks.  Each request's decode position rides in SMEM as a
 scalar-prefetch argument.
+
+``mla_decode_attention`` is the same online softmax for multi-head
+latent attention: one latent cache row shared by every head, whose
+first columns are also the values.
 """
 from __future__ import annotations
 
@@ -142,6 +146,84 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     )(pos.astype(jnp.int32), q.reshape(B, Hkv, rep, hd), k_cache, v_cache,
       cache_pos.reshape(B, 1, W))
     return out.reshape(B, H, hd)
+
+
+def _latent_kernel(pos_ref, q_ref, kv_ref, cpos_ref, o_ref,
+                   acc_ref, m_ref, l_ref, *, nw: int, v_dim: int,
+                   scale: float):
+    """One (Wb, D) block of the latent cache for every head of a row:
+    scores over all D columns, values from the first ``v_dim``."""
+    w_step = pl.program_id(1)
+
+    @pl.when(w_step == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    ok = _valid(cpos_ref[...], pos_ref[pl.program_id(0)], 0)   # (1, Wb)
+    kv = kv_ref[...]                                           # (Wb, D)
+    s = jax.lax.dot_general(q_ref[...], kv, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(ok, s, -1e30)                                # (H, Wb)
+    m_old = m_ref[...]                                         # (H, 1)
+    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_old - m_new)
+    p = jnp.exp(s - m_new) * ok.astype(jnp.float32)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p.astype(kv.dtype), kv_ref[:, :v_dim], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+    @pl.when(w_step == nw - 1)
+    def _():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                      ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("v_dim", "scale", "wb", "interpret"))
+def mla_decode_attention(q: jax.Array, latent: jax.Array,
+                         cache_pos: jax.Array, pos: jax.Array, *,
+                         v_dim: int, scale: float, wb: int = 512,
+                         interpret: Optional[bool] = None) -> jax.Array:
+    """Absorbed multi-head latent attention decode (``models.mla``).
+
+    q: (B, H, D), the latent and rotary query of every head; latent:
+    (B, W, D), one cache row shared by all heads (the latent, then the
+    rotary key); cache_pos (B, W); pos (B,).  Returns (B, H, v_dim): the
+    values are the latent's first ``v_dim`` columns, so each (Wb, D)
+    block is read from HBM once per step for keys and values alike.
+    Grid (B, W/Wb), KV length innermost, online softmax over (H, v_dim)
+    f32 accumulators — ``models.attention.latent_decode_attention`` is
+    the oracle.  With D = 576 and H = 128 a cached position costs 1152
+    bytes and 2 * 128 * (576 + v_dim) flops, near the v5e's ridge point.
+    """
+    B, H, D = q.shape
+    W = latent.shape[1]
+    wb = _kv_block(W, wb)
+    grid = (B, W // wb)
+    row = pl.BlockSpec((None, H, D), lambda b, w, p: (b, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=grid,
+        in_specs=[row,
+                  pl.BlockSpec((None, wb, D), lambda b, w, p: (b, w, 0)),
+                  pl.BlockSpec((None, 1, wb), lambda b, w, p: (b, 0, w))],
+        out_specs=pl.BlockSpec((None, H, v_dim), lambda b, w, p: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((H, v_dim), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, nw=grid[1], v_dim=v_dim,
+                          scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, v_dim), q.dtype),
+        interpret=interpret_mode(interpret),
+        name="mla_decode_attention",
+    )(pos.astype(jnp.int32), q, latent, cache_pos.reshape(B, 1, W))
 
 
 def _paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, ppos_ref, o_ref,

@@ -10,13 +10,15 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention import (decode_attention,
+                                            mla_decode_attention,
                                             paged_decode_attention)
 from repro.kernels.gating_topk import gating_dispatch, gating_topk
 from repro.kernels.grouped_matmul import grouped_matmul
 from repro.models.common import activation
 
 __all__ = ["decode_attention", "gating_dispatch", "gating_topk",
-           "grouped_matmul", "grouped_mlp", "paged_decode_attention"]
+           "grouped_matmul", "grouped_mlp", "mla_decode_attention",
+           "paged_decode_attention"]
 
 
 def grouped_mlp(xe, w1, w3, w2, act: str = "silu", row_valid=None, **kw):

@@ -60,7 +60,8 @@ def kernel_names(hlo_text: str) -> list:
     names = set()
     for line in hlo_text.splitlines():
         if 'custom_call_target="tpu_custom_call"' in line:
-            names.add(line.split("=")[0].strip().lstrip("%").split(".")[0])
+            name = line.split("=")[0].strip().removeprefix("ROOT ")
+            names.add(name.lstrip("%").split(".")[0])
     return sorted(names)
 
 

@@ -13,7 +13,6 @@ Usage:
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import time
 from typing import Optional
 
@@ -21,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config import get_config, reduced
+from repro.config import chip_share, get_config, reduced
 from repro.core.disagg import STAGES, DisaggPlan, DisaggregatedInstance
 from repro.core.transport import HOP_KINDS, make_transport
 from repro.launch.compile_cache import enable_compile_cache
@@ -140,7 +139,9 @@ def run(arch: Optional[str] = None, *,
     (``jax.distributed`` multi-controller).
 
     ``n_layers`` > 0 serves the config cut to that depth, every width
-    kept (with ``use_reduced=False``: the published widths); ``dtype``
+    kept (with ``use_reduced=False``: the published widths);
+    ``lead_layers``, ``experts_held`` and ``vocab`` cut it further to one
+    chip's share (``config.chip_share``); ``dtype``
     is the weights' and KV cache's dtype.  Parameters are initialised
     in one jitted program, so each weight is drawn and cast in place
     (no float32 copy of a bfloat16 model).
@@ -155,8 +156,8 @@ def run(arch: Optional[str] = None, *,
     cfg = get_config(sc.arch)
     if sc.use_reduced:
         cfg = reduced(cfg)
-    if sc.n_layers:
-        cfg = dataclasses.replace(cfg, n_layers=sc.n_layers)
+    cfg = chip_share(cfg, n_layers=sc.n_layers, lead_layers=sc.lead_layers,
+                     experts_held=sc.experts_held, vocab=sc.vocab)
     dtype = jnp.dtype(sc.dtype)
     params = jax.jit(init_params, static_argnums=(0, 2))(
         cfg, jax.random.PRNGKey(sc.seed), dtype)

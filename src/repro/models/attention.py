@@ -69,7 +69,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qs = qp.reshape(B, n_chunks, q_chunk, H, hd).swapaxes(0, 1)
     ps = pp.reshape(B, n_chunks, q_chunk).swapaxes(0, 1)
     outs = jax.lax.map(lambda args: chunk_attn(*args), (qs, ps))
-    out = outs.swapaxes(0, 1).reshape(B, n_chunks * q_chunk, H, hd)
+    out = outs.swapaxes(0, 1).reshape(B, n_chunks * q_chunk, H, v.shape[-1])
     return out[:, :Sq]
 
 
@@ -96,6 +96,26 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     s = s + bias[:, None, None, None, :]
     p = jax.nn.softmax(s, axis=-1)
     return _grouped_out(p, v_cache, q.dtype)[:, 0]
+
+
+def latent_decode_attention(q: jax.Array, latent: jax.Array,
+                            cache_pos: jax.Array, pos: jax.Array, *,
+                            v_dim: int, scale: float) -> jax.Array:
+    """Absorbed multi-head latent attention for one query token a row
+    (``models.mla``): every head attends over one shared latent cache.
+
+    q: (B, H, D) — the latent query and the rotary query side by side;
+    latent: (B, W, D) — the cached latent and rotary key; the values are
+    the latent's first ``v_dim`` columns.  Returns (B, H, v_dim).
+    """
+    s = jnp.einsum("bhd,bwd->bhw", q.astype(jnp.float32),
+                   latent.astype(jnp.float32)) * scale
+    ok = (cache_pos >= 0) & (cache_pos <= pos[:, None])
+    s = jnp.where(ok[:, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhw,bwd->bhd", p,
+                     latent[..., :v_dim].astype(jnp.float32))
+    return out.astype(q.dtype)
 
 
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,

@@ -1,8 +1,12 @@
-"""Shared model building blocks: norms, RoPE, softcap, init helpers."""
+"""Shared model building blocks: norms, RoPE and its YaRN scaling,
+softcap, init helpers."""
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -25,13 +29,63 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN's rotary frequencies (HF ``DeepseekV3YarnRotaryEmbedding``):
+    the base frequencies where a dimension turns more than ``beta_fast``
+    times over the original context, the base divided by ``factor``
+    where it turns fewer than ``beta_slow`` times, a linear ramp between.
+    ``yarn``: a ``config.YarnConfig``.  Returns (dim/2,) float32."""
+    exps = np.arange(0, dim, 2, dtype=np.float32) / np.float32(dim)
+    extra = np.float32(1.0) / np.float32(theta) ** exps
+    inter = np.float32(1.0) / (np.float32(yarn.factor)
+                               * np.float32(theta) ** exps)
+
+    def turns_dim(rotations: float) -> float:
+        return (dim * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(turns_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / np.float32(high - low), 0.0, 1.0)
+    keep = np.float32(1.0) - ramp
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def yarn_rope_scale(yarn) -> float:
+    """The factor YaRN puts on cos and sin."""
+    return (_yarn_mscale(yarn.factor, yarn.mscale)
+            / _yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+
+
+def yarn_softmax_scale(head_dim: int, yarn) -> float:
+    """head_dim ** -0.5, times YaRN's attention factor squared where the
+    configuration sets ``mscale_all_dim``."""
+    scale = head_dim ** -0.5
+    if yarn is not None and yarn.mscale_all_dim:
+        m = _yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs=None) -> jax.Array:
     """Rotary position embedding.
 
     x: (..., seq, n_heads, head_dim), positions: (..., seq) int32.
+    ``freqs``: (head_dim/2,) frequencies in place of ``theta``'s own
+    (YaRN's, for instance).
     """
     head_dim = x.shape[-1]
-    freqs = rope_frequencies(head_dim, theta)                 # (hd/2,)
+    if freqs is None:
+        freqs = rope_frequencies(head_dim, theta)             # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     sin = jnp.sin(angles)[..., None, :]                       # (..., seq, 1, hd/2)
     cos = jnp.cos(angles)[..., None, :]

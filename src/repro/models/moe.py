@@ -47,6 +47,62 @@ def route(x: jax.Array, w_router: jax.Array, top_k: int,
     return Routing(gates, experts.astype(jnp.int32), probs)
 
 
+def route_sigmoid(x: jax.Array, w_router: jax.Array, cfg: MoEConfig,
+                  score_bias: jax.Array | None = None,
+                  bias: jax.Array | None = None) -> Routing:
+    """DeepSeek-V3's group-limited sigmoid routing (``noaux_tc``).
+
+    scores = sigmoid(x W_r) in f32.  Experts are chosen on scores +
+    ``score_bias`` (the per-expert correction bias): a group's score is
+    the sum of its two best, the ``topk_group`` best of ``n_group``
+    groups are kept, and the ``top_k`` best experts inside them are
+    chosen.  The bias chooses and does not weigh: the weights are the
+    chosen experts' unbiased scores, normalised to sum 1, times
+    ``routed_scaling``.  ``bias``: optional (E,) additive logit bias, as
+    in ``route``.  ``probs`` of the result holds the scores."""
+    logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
+    if bias is not None:
+        logits = logits + bias.astype(jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores
+    if score_bias is not None:
+        choice = scores + score_bias.astype(jnp.float32)
+    T, E = scores.shape
+    G = cfg.n_group
+    groups = choice.reshape(T, G, E // G)
+    group_score = jnp.sum(jax.lax.top_k(groups, min(2, E // G))[0], axis=-1)
+    _, kept = jax.lax.top_k(group_score, cfg.topk_group)
+    keep = jnp.sum(jax.nn.one_hot(kept, G, dtype=jnp.int32), axis=1) > 0
+    keep = jnp.repeat(keep, E // G, axis=-1)                       # (T, E)
+    _, experts = jax.lax.top_k(jnp.where(keep, choice, -jnp.inf), cfg.top_k)
+    gates = jnp.take_along_axis(scores, experts, axis=-1)
+    gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    return Routing(gates * cfg.routed_scaling, experts.astype(jnp.int32),
+                   scores)
+
+
+def route_for(params: dict, x: jax.Array, cfg: MoEConfig) -> Routing:
+    """The routing ``cfg`` asks for, from the layer's router weights."""
+    if cfg.scoring == "sigmoid":
+        return route_sigmoid(x, params["router"], cfg,
+                             params.get("score_bias"),
+                             params.get("router_bias"))
+    return route(x, params["router"], cfg.top_k, params.get("router_bias"))
+
+
+def require_plain_routing(cfg: MoEConfig, where: str):
+    """Refuse, in ``where``, routing that only the monolithic dense path
+    serves: sigmoid group-limited scores, or a share of held experts."""
+    if cfg.scoring != "softmax":
+        raise NotImplementedError(
+            f"{where} does not serve {cfg.scoring} group-limited routing "
+            f"yet; serve it with the monolithic runtime")
+    if not cfg.holds_all:
+        raise NotImplementedError(
+            f"{where} does not serve held experts ({cfg.held} of "
+            f"{cfg.n_experts}) yet; serve them with the monolithic runtime")
+
+
 def routing_counts(routing: Routing, n_experts: int,
                    weights: jax.Array | None = None) -> jax.Array:
     """Per-expert routed-token counts for one flat batch: (E,) f32.
@@ -116,6 +172,12 @@ def expert_capacity(n_tokens: int, cfg: MoEConfig, mode: str) -> int:
     return min(c, n_tokens)
 
 
+def routed_rows(cfg: MoEConfig, n_tokens: int, mode: str) -> int:
+    """Rows the routed-expert matmuls of ``routed_experts_dense`` compute
+    for a batch of ``n_tokens``: held experts x capacity."""
+    return cfg.held * expert_capacity(n_tokens, cfg, mode)
+
+
 def dispatch_indices(routing: Routing, n_experts: int, capacity: int,
                      valid: jax.Array | None = None):
     """Compute per-(token,k) slot positions and the (E, C) index buffers.
@@ -168,11 +230,19 @@ def routed_experts_dense(params: dict, x: jax.Array, cfg: MoEConfig, act: str,
     """Baseline routed-expert computation (monolithic scatter/gather)."""
     T, d = x.shape
     with jax.named_scope("router"):
-        routing = route(x, params["router"], cfg.top_k,
-                        params.get("router_bias"))
-        aux = load_balance_loss(routing, cfg.n_experts)
+        routing = route_for(params, x, cfg)
+        aux = (load_balance_loss(routing, cfg.n_experts)
+               if cfg.scoring == "softmax" else jnp.zeros((), jnp.float32))
         C = expert_capacity(T, cfg, capacity_mode)
-        idx_buf, gate_buf = dispatch_indices(routing, cfg.n_experts, C)
+        if cfg.holds_all:
+            idx_buf, gate_buf = dispatch_indices(routing, cfg.n_experts, C)
+        else:
+            # only the (token, k) pairs routed to a held expert
+            local = routing.experts - cfg.first_held
+            held = (local >= 0) & (local < cfg.held)
+            idx_buf, gate_buf = dispatch_indices(
+                Routing(routing.gates, jnp.where(held, local, 0),
+                        routing.probs), cfg.held, C, valid=held)
 
     with jax.named_scope("experts"):
         # gather tokens into (E, C, d) expert buffers
@@ -194,18 +264,22 @@ def moe_ffn(params: dict, x: jax.Array, cfg: MoEConfig, act: str,
             capacity_mode: str = "train"):
     """MoE FFN over a flat token batch.
 
-    params: {"router": (d,E), "we1"/"we3": (E,d,ffe), "we2": (E,ffe,d),
-             optional shared expert ws1/ws3/ws2 + "shared_gate": (d,),
-             optional dense residual wd1/wd3/wd2}
+    params: {"router": (d,E), "we1"/"we3": (E_held,d,ffe),
+             "we2": (E_held,ffe,d), optional "score_bias": (E,) (sigmoid
+             routing), optional shared expert ws1/ws3/ws2 (+ optional
+             "shared_gate": (d,)), optional dense residual wd1/wd3/wd2}
     x: (T, d).  Returns (y: (T, d), aux_loss: scalar f32).
     """
     impl = _ROUTED_IMPL if _ROUTED_IMPL is not None else routed_experts_dense
     y, aux = impl(params, x, cfg, act, capacity_mode)
 
-    if "ws1" in params:  # qwen2-moe shared experts (always active)
+    if "ws1" in params:  # shared experts (always active)
         shared = gated_ffn(x, params["ws1"], params["ws3"], params["ws2"], act)
-        g = jax.nn.sigmoid(x.astype(jnp.float32) @ params["shared_gate"].astype(jnp.float32))
-        y = y + (g[:, None] * shared.astype(jnp.float32)).astype(x.dtype)
+        if "shared_gate" in params:     # qwen2-moe: a sigmoid gate
+            g = jax.nn.sigmoid(x.astype(jnp.float32) @ params["shared_gate"].astype(jnp.float32))
+            y = y + (g[:, None] * shared.astype(jnp.float32)).astype(x.dtype)
+        else:                           # deepseek: added as it is
+            y = y + shared
     if "wd1" in params:  # arctic parallel dense residual
         y = y + gated_ffn(x, params["wd1"], params["wd3"], params["wd2"], act)
     return y, aux

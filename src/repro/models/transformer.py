@@ -20,11 +20,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.config import ModelConfig
+from repro.config import MLA_KINDS, ModelConfig
 from repro.models import attention as attn_lib
 from repro.models.common import (activation, apply_rope, dense_init, rms_norm,
                                  softcap, split_keys)
 from repro.models.ffn import gated_ffn
+from repro.models.mla import init_mla, mla_decode_sublayer, mla_sublayer
 from repro.models.moe import moe_ffn
 from repro.models.rglru import rglru_block, rglru_block_step
 from repro.models.ssd import ssd_block, ssd_block_step
@@ -34,16 +35,19 @@ from repro.models.ssd import ssd_block, ssd_block_step
 # ---------------------------------------------------------------------------
 
 
-def _init_ffn(key, cfg: ModelConfig, dtype) -> dict:
+def _init_ffn(key, cfg: ModelConfig, dtype, dense: bool = False) -> dict:
+    """A layer's FFN: the MoE where the model has one (the routed experts
+    this layer holds), else — or with ``dense`` — a gated FFN of
+    ``d_ff``."""
     d = cfg.d_model
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense:
         m = cfg.moe
         ks = split_keys(key, 12)
         p = {
             "router": dense_init(ks[0], (d, m.n_experts), jnp.float32),
-            "we1": dense_init(ks[1], (m.n_experts, d, m.d_ff_expert), dtype),
-            "we3": dense_init(ks[2], (m.n_experts, d, m.d_ff_expert), dtype),
-            "we2": dense_init(ks[3], (m.n_experts, m.d_ff_expert, d), dtype),
+            "we1": dense_init(ks[1], (m.held, d, m.d_ff_expert), dtype),
+            "we3": dense_init(ks[2], (m.held, d, m.d_ff_expert), dtype),
+            "we2": dense_init(ks[3], (m.held, m.d_ff_expert, d), dtype),
         }
         if m.n_shared_experts:
             ff_s = m.d_ff_shared
@@ -51,8 +55,10 @@ def _init_ffn(key, cfg: ModelConfig, dtype) -> dict:
                 "ws1": dense_init(ks[4], (d, ff_s), dtype),
                 "ws3": dense_init(ks[5], (d, ff_s), dtype),
                 "ws2": dense_init(ks[6], (ff_s, d), dtype),
-                "shared_gate": dense_init(ks[7], (d,), jnp.float32, scale=0.02),
             })
+            if m.shared_gated:
+                p["shared_gate"] = dense_init(ks[7], (d,), jnp.float32,
+                                              scale=0.02)
         if m.d_ff_dense_residual:
             ff_d = m.d_ff_dense_residual
             p.update({
@@ -60,6 +66,9 @@ def _init_ffn(key, cfg: ModelConfig, dtype) -> dict:
                 "wd3": dense_init(ks[9], (d, ff_d), dtype),
                 "wd2": dense_init(ks[10], (ff_d, d), dtype),
             })
+        if m.scoring == "sigmoid":  # the correction bias, N(0, 0.01^2)
+            p["score_bias"] = 0.01 * jax.random.normal(
+                ks[11], (m.n_experts,), jnp.float32)
         return p
     return {
         "w1": dense_init(jax.random.fold_in(key, 1), (d, cfg.d_ff), dtype),
@@ -91,6 +100,9 @@ def init_layer_params(key, kind: str, cfg: ModelConfig, dtype) -> dict:
     if kind in ("attn", "local"):
         p.update(_init_attn_proj(ks[0], cfg, dtype))
         p.update(_init_ffn(ks[1], cfg, dtype))
+    elif kind in MLA_KINDS:
+        p.update(init_mla(ks[0], cfg, dtype))
+        p.update(_init_ffn(ks[1], cfg, dtype, dense=kind == "mla_dense"))
     elif kind == "cross":  # llama-3.2-vision gated cross-attention layer
         p.update(_init_attn_proj(ks[0], cfg, dtype))
         p.update(_init_ffn(ks[1], cfg, dtype))
@@ -156,6 +168,11 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.float32) -> dict:
     params["remainder"] = tuple(
         init_layer_params(jax.random.fold_in(keys[3], i), kind, cfg, dtype)
         for i, kind in enumerate(cfg.remainder_pattern))
+    if cfg.lead_pattern:
+        params["lead"] = tuple(
+            init_layer_params(jax.random.fold_in(keys[5], i), kind, cfg,
+                              dtype)
+            for i, kind in enumerate(cfg.lead_pattern))
 
     if cfg.encoder is not None:
         enc_keys = split_keys(keys[4], cfg.encoder.n_layers + 2)
@@ -187,7 +204,7 @@ def _ffn_sublayer(p, x2d_shape_x, cfg: ModelConfig, capacity_mode: str):
     x = x2d_shape_x
     B, T, d = x.shape
     h = rms_norm(x, p["ln2"])
-    if cfg.moe is not None:
+    if "router" in p:
         y, aux = moe_ffn(p, h.reshape(B * T, d), cfg.moe, cfg.act, capacity_mode)
         y = y.reshape(B, T, d)
     else:
@@ -248,6 +265,13 @@ def apply_layer_seq(kind: str, p: dict, cfg: ModelConfig, x: jax.Array,
         delta, cache = _self_attn_sublayer(
             p, x, cfg, positions, causal=causal, window=window,
             build_cache=build_cache, cache_len=W)
+        x = x + delta
+        dff, aux = _ffn_sublayer(p, x, cfg, capacity_mode)
+        x = x + dff
+    elif kind in MLA_KINDS:
+        delta, cache = mla_sublayer(p, cfg, x, positions,
+                                    build_cache=build_cache,
+                                    cache_len=max_seq)
         x = x + delta
         dff, aux = _ffn_sublayer(p, x, cfg, capacity_mode)
         x = x + dff
@@ -346,7 +370,7 @@ def ffn_decode_sublayer(p: dict, cfg: ModelConfig, x: jax.Array,
                         capacity_mode: str):
     """Decode-mode FFN sublayer.  Returns (delta, aux)."""
     h = rms_norm(x, p["ln2"])
-    if cfg.moe is not None:
+    if "router" in p:
         y, aux = moe_ffn(p, h, cfg.moe, cfg.act, capacity_mode)
     else:
         y = gated_ffn(h, p["w1"], p["w3"], p["w2"], cfg.act)
@@ -375,6 +399,12 @@ def apply_layer_decode(kind: str, p: dict, cfg: ModelConfig, x: jax.Array,
     if kind in ("attn", "local"):
         window = cfg.window if kind == "local" else 0
         delta, cache = self_attn_decode(p, x, cache, window)
+        x = x + delta
+        dff, aux = ffn_decode(p, x)
+        x = x + dff
+    elif kind in MLA_KINDS:
+        delta, cache = mla_decode_sublayer(p, cfg, x, pos, cache,
+                                           use_kernels=use_kernels)
         x = x + delta
         dff, aux = ffn_decode(p, x)
         x = x + dff
@@ -429,6 +459,10 @@ def init_cache_entry(kind: str, cfg: ModelConfig, batch: int, max_seq: int,
 
     if kind == "attn":
         return kv(max_seq)
+    if kind in MLA_KINDS:
+        return {"latent": jnp.zeros((batch, max_seq, cfg.mla.latent_dim),
+                                    dtype),
+                "pos": jnp.full((batch, max_seq), -1, jnp.int32)}
     if kind == "local":
         return kv(min(cfg.window, max_seq))
     if kind == "cross":
@@ -459,7 +493,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype) -> dict:
         return jax.tree.map(
             lambda a: jnp.broadcast_to(a, (cfg.n_blocks,) + a.shape).copy(), entry)
 
-    return {
+    cache = {
         "blocks": tuple(
             stack(init_cache_entry(kind, cfg, batch, max_seq, dtype))
             for kind in cfg.block_pattern),
@@ -467,6 +501,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype) -> dict:
             init_cache_entry(kind, cfg, batch, max_seq, dtype)
             for kind in cfg.remainder_pattern),
     }
+    if cfg.lead_pattern:
+        cache["lead"] = tuple(
+            init_cache_entry(kind, cfg, batch, max_seq, dtype)
+            for kind in cfg.lead_pattern)
+    return cache
 
 
 # ---------------------------------------------------------------------------
@@ -534,6 +573,14 @@ def _seq_pass(params, cfg: ModelConfig, x, positions, source, capacity_mode,
     pattern = cfg.block_pattern
     aux0 = jnp.zeros((), jnp.float32)
 
+    lead_caches = []
+    for i, kind in enumerate(cfg.lead_pattern):
+        x, c, a = apply_layer_seq(kind, params["lead"][i], cfg, x, positions,
+                                  source=source, capacity_mode=capacity_mode,
+                                  build_cache=build_cache, max_seq=max_seq)
+        aux0 = aux0 + a
+        lead_caches.append(c)
+
     def body(carry, bp):
         x, aux = carry
         caches = []
@@ -565,6 +612,8 @@ def _seq_pass(params, cfg: ModelConfig, x, positions, source, capacity_mode,
         rem_caches.append(c)
     cache = ({"blocks": block_caches, "remainder": tuple(rem_caches)}
              if build_cache else None)
+    if build_cache and cfg.lead_pattern:
+        cache["lead"] = tuple(lead_caches)
     return x, aux, cache
 
 
@@ -633,6 +682,13 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
     x = _embed_tokens(params, cfg, tokens)
     pattern = cfg.block_pattern
 
+    new_lead = []
+    for i, kind in enumerate(cfg.lead_pattern):
+        x, c, _ = apply_layer_decode(kind, params["lead"][i], cfg, x, pos,
+                                     cache["lead"][i], capacity_mode,
+                                     use_kernels=use_kernels)
+        new_lead.append(c)
+
     def body(x, xs):
         bp, bc = xs
         new_caches = []
@@ -654,4 +710,6 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
                                      use_kernels=use_kernels)
         new_rem.append(c)
     new_cache = {"blocks": new_block_caches, "remainder": tuple(new_rem)}
+    if cfg.lead_pattern:
+        new_cache["lead"] = tuple(new_lead)
     return _lm_head(params, cfg, x), new_cache

@@ -35,6 +35,9 @@ class ServingConfig:
     arch: str = "mixtral-8x22b"
     use_reduced: bool = True
     n_layers: int = 0                  # >0 cuts depth; widths stay as given
+    lead_layers: int = 0               # >0 keeps this many leading layers
+    experts_held: int = 0              # >0 routed experts held a MoE layer
+    vocab: int = 0                     # >0 serves this slice of the vocabulary
     dtype: str = "float32"             # weights + KV cache: float32 | bfloat16
     runtime: str = "monolithic"        # monolithic | disagg | pingpong
     n_requests: int = 8
@@ -77,8 +80,10 @@ class ServingConfig:
         if self.dtype not in DTYPES:
             raise ValueError(f"dtype must be one of {DTYPES}, "
                              f"got {self.dtype!r}")
-        if self.n_layers < 0:
-            raise ValueError(f"n_layers must be >= 0, got {self.n_layers}")
+        for name in ("n_layers", "lead_layers", "experts_held", "vocab"):
+            if getattr(self, name) < 0:
+                raise ValueError(f"{name} must be >= 0, got "
+                                 f"{getattr(self, name)}")
         if self.runtime not in RUNTIMES:
             raise ValueError(f"runtime must be one of {RUNTIMES}, "
                              f"got {self.runtime!r}")

@@ -43,6 +43,7 @@ from repro.config import ModelConfig
 from repro.core.load_balance import balance_experts, evaluate_placement
 from repro.core.transport import InProcessTransport
 from repro.models import decode_step, init_cache, prefill
+from repro.models.moe import routed_rows
 from repro.models.stubs import extra_inputs
 from repro.serving.config import ServingConfig
 from repro.serving.kvcache import (MicrobatchSlotAllocator, SlotAllocator,
@@ -259,6 +260,13 @@ class Engine:
             lambda toks, cache, pos: decode_step(
                 self.params, cfg, toks, cache, pos,
                 use_kernels=base.use_kernels))
+        # rows the routed-expert matmuls of one default decode call
+        # compute (its capacity is drop-free, C = max_batch), counted
+        # from static shapes as ``expert_rows``
+        self._expert_rows = (
+            routed_rows(cfg.moe, max_batch, "full") * cfg.n_moe_layers
+            if cfg.moe is not None and decode_fn is None
+            and mode == "monolithic" else 0)
         self._last_token = np.zeros((max_batch,), np.int32)
         # logits (B, V) of the first and the latest decode iteration:
         # every runtime decodes the same first step from the same
@@ -596,6 +604,8 @@ class Engine:
                     toks, cache, pos, self.mb_slices)
             else:
                 logits, cache = self._decode(toks, cache, pos)
+                if self._expert_rows:
+                    self.obs.count("expert_rows", self._expert_rows)
         if self.kv_layout == "paged":
             with span("engine.writeback"):
                 self._paged_writeback(cache)
@@ -612,13 +622,14 @@ class Engine:
             rids = np.zeros((self.max_batch,), np.int64)
             for req in self.running.values():
                 rids[req.slot] = req.rid
-            nxt = sample_rows(logits, k, rids, self.sampling)
+            # one read of the whole batch's tokens
+            nxt = np.asarray(sample_rows(logits, k, rids, self.sampling))
             for req in self.running.values():
                 tok = int(nxt[req.slot])
                 req.generated.append(tok)
                 self._last_token[req.slot] = tok
         n_active = len(self.running)
-        self.obs.count("host_syncs", n_active)     # one int() per row
+        self.obs.count("host_syncs")
         self.obs.count("decode_steps")
         self.n_decode_iters += 1
         if (self.expert_rebalance_every

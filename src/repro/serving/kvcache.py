@@ -33,11 +33,17 @@ from repro.core import transport as transport_lib
 from repro.core.pingpong import even_partition
 
 
+def _unstacked(cache) -> List[str]:
+    """The cache's groups of unstacked layers: the remainder, and the
+    leading layers where the model has them."""
+    return [k for k in ("lead", "remainder") if k in cache]
+
+
 def insert_rows(global_cache, request_cache, row: int):
     """Write a single-request cache (batch dim 1) into row ``row``.
 
-    Leaves shaped (n_blocks, 1, ...) go into (n_blocks, B, ...); remainder
-    leaves shaped (1, ...) into (B, ...).
+    Leaves shaped (n_blocks, 1, ...) go into (n_blocks, B, ...); lead and
+    remainder leaves shaped (1, ...) into (B, ...).
     """
 
     def ins(full, part):
@@ -48,26 +54,26 @@ def insert_rows(global_cache, request_cache, row: int):
     def ins_blocks(full_entry, part_entry):
         return jax.tree.map(ins, full_entry, part_entry)
 
-    return {
-        "blocks": tuple(ins_blocks(f, p) for f, p in
-                        zip(global_cache["blocks"], request_cache["blocks"])),
-        "remainder": tuple(
+    out = {"blocks": tuple(ins_blocks(f, p) for f, p in
+                           zip(global_cache["blocks"],
+                               request_cache["blocks"]))}
+    for key in _unstacked(global_cache):
+        out[key] = tuple(
             jax.tree.map(lambda f, p: f.at[row].set(p[0]), f_e, p_e)
-            for f_e, p_e in zip(global_cache["remainder"],
-                                request_cache["remainder"])),
-    }
+            for f_e, p_e in zip(global_cache[key], request_cache[key]))
+    return out
 
 
 def extract_row(global_cache, row: int):
     """Slice one request's cache out of a batched cache (inverse of
     ``insert_rows``): blocks leaves (n_blocks, B, ...) -> (n_blocks, 1, ...),
-    remainder leaves (B, ...) -> (1, ...)."""
-    return {
-        "blocks": tuple(jax.tree.map(lambda a: a[:, row:row + 1], e)
-                        for e in global_cache["blocks"]),
-        "remainder": tuple(jax.tree.map(lambda a: a[row:row + 1], e)
-                           for e in global_cache["remainder"]),
-    }
+    lead and remainder leaves (B, ...) -> (1, ...)."""
+    out = {"blocks": tuple(jax.tree.map(lambda a: a[:, row:row + 1], e)
+                           for e in global_cache["blocks"])}
+    for key in _unstacked(global_cache):
+        out[key] = tuple(jax.tree.map(lambda a: a[row:row + 1], e)
+                         for e in global_cache[key])
+    return out
 
 
 def migrate_kv(decode_cache, request_cache, row: int, *, sharding=None,
@@ -147,10 +153,8 @@ def reset_row(global_cache, cfg: ModelConfig, row: int, max_seq: int):
             out["ssm"] = s.at[idx].set(0)
         return out
 
-    return {
-        "blocks": tuple(rst_entry(e) for e in global_cache["blocks"]),
-        "remainder": tuple(rst_entry(e) for e in global_cache["remainder"]),
-    }
+    return {key: tuple(rst_entry(e) for e in global_cache[key])
+            for key in ["blocks"] + _unstacked(global_cache)}
 
 
 class SlotAllocator:

@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config import ModelConfig
+from repro.config import MLA_KINDS, ModelConfig
 from repro.models import init_cache
 
 KV_KEYS = frozenset(("k", "v", "pos"))
@@ -72,7 +72,11 @@ def paged_supported(cfg: ModelConfig, max_seq: int,
     if max_seq % page_size:
         return False, (f"max_seq={max_seq} is not a whole number of "
                        f"pages of {page_size}")
-    for kind in cfg.block_pattern + cfg.remainder_pattern:
+    for kind in cfg.layer_kinds:
+        if kind in MLA_KINDS:
+            return False, (f"layer kind {kind!r}: multi-head latent "
+                           f"attention's latent cache is not yet served "
+                           f"by the paged layout")
         if kind not in ("attn", "local"):
             return False, (f"layer kind {kind!r} carries non-KV cache "
                            f"state (paged layout pages only k/v/pos)")

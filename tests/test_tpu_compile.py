@@ -57,6 +57,17 @@ def test_decode_attention(chip, W):
                     ((B,), I32)) == ["decode_attention"]
 
 
+@pytest.mark.parametrize("W", [512, 2048])      # one latent block, and four
+def test_mla_decode_attention(chip, W):
+    """DeepSeek-V3's absorbed latent decode: 128 heads over one
+    (W, 512 + 64) latent row."""
+    B, HL, DL, VL = 4, 128, 576, 512
+    fn = lambda q, lat, cp, p: ops.mla_decode_attention(
+        q, lat, cp, p, v_dim=VL, scale=0.1, interpret=False)
+    assert _compile(chip, fn, ((B, HL, DL), BF), ((B, W, DL), BF),
+                    ((B, W), I32), ((B,), I32)) == ["mla_decode_attention"]
+
+
 def test_paged_decode_attention(chip):
     B, P, ps, n_logical = 4, 64, 16, 8
     fn = lambda q, k, v, pp, bt, p: ops.paged_decode_attention(
