@@ -47,8 +47,8 @@ from repro.models.moe import routed_rows
 from repro.models.stubs import extra_inputs
 from repro.serving.config import ServingConfig
 from repro.serving.kvcache import (MicrobatchSlotAllocator, SlotAllocator,
-                                   insert_rows, mb_slot_ranges, migrate_kv,
-                                   migrate_pages, reset_row)
+                                   mb_slot_ranges, migrate_kv, migrate_pages,
+                                   reset_row, write_row)
 from repro.serving.pages import PagePool, n_pages_for
 from repro.serving.prefill import suffix_prefill
 from repro.serving.prefix_cache import PrefixCache
@@ -59,6 +59,11 @@ from repro.serving.stats import (STATS_SCHEMA_VERSION, CounterStats,
 # sentinel distinguishing "kwarg not passed" from an explicit value, so
 # the deprecated scalar aliases below can coexist with ``config=``
 _UNSET = object()
+
+# inline admission's prefill: one program per (config, prompt length,
+# max_seq), so the layer scan is traced once, not at every admission
+_prefill = jax.jit(prefill, static_argnames=("cfg", "max_seq",
+                                             "capacity_mode"))
 
 
 @dataclass
@@ -447,10 +452,10 @@ class Engine:
             with self.obs.span("engine.prefill", **ids):
                 toks = jnp.asarray([req.prompt], jnp.int32)
                 extras = extra_inputs(self.cfg, 1)
-                last_logits, rcache = prefill(self.params, self.cfg, toks,
-                                              max_seq=self.max_seq, **extras)
+                last_logits, rcache = _prefill(self.params, self.cfg, toks,
+                                               self.max_seq, **extras)
             with self.obs.span("engine.insert", **ids):
-                self.cache = insert_rows(self.cache, rcache, slot)
+                self.cache = write_row(self.cache, rcache, slot)
             self._start_request(req, slot, last_logits)
 
     def _admit_from_transfer_queue(self):

@@ -23,10 +23,13 @@ behind the row differs.
 """
 from __future__ import annotations
 
+import functools
 from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import numpy as np
+from jax import lax
 
 from repro.config import ModelConfig
 from repro.core import transport as transport_lib
@@ -39,29 +42,47 @@ def _unstacked(cache) -> List[str]:
     return [k for k in ("lead", "remainder") if k in cache]
 
 
-def insert_rows(global_cache, request_cache, row: int):
+def insert_rows(global_cache, request_cache, row):
     """Write a single-request cache (batch dim 1) into row ``row``.
 
     Leaves shaped (n_blocks, 1, ...) go into (n_blocks, B, ...); lead and
-    remainder leaves shaped (1, ...) into (B, ...).
+    remainder leaves shaped (1, ...) into (B, ...).  Each write is a
+    ``dynamic_update_slice``, so ``row`` may be a traced scalar.
     """
 
-    def ins(full, part):
-        if part.ndim == full.ndim:  # stacked blocks: (n_blocks, B, ...)
-            return full.at[:, row].set(part[:, 0])
-        raise ValueError((full.shape, part.shape))
+    def into(axis: int):
+        def put(full, part):
+            if part.ndim != full.ndim:
+                raise ValueError((full.shape, part.shape))
+            return lax.dynamic_update_slice_in_dim(
+                full, part.astype(full.dtype), row, axis)
+        return put
 
-    def ins_blocks(full_entry, part_entry):
-        return jax.tree.map(ins, full_entry, part_entry)
-
-    out = {"blocks": tuple(ins_blocks(f, p) for f, p in
-                           zip(global_cache["blocks"],
-                               request_cache["blocks"]))}
+    out = {"blocks": jax.tree.map(into(1), global_cache["blocks"],
+                                  request_cache["blocks"])}
     for key in _unstacked(global_cache):
-        out[key] = tuple(
-            jax.tree.map(lambda f, p: f.at[row].set(p[0]), f_e, p_e)
-            for f_e, p_e in zip(global_cache[key], request_cache[key]))
+        out[key] = jax.tree.map(into(0), global_cache[key],
+                                request_cache[key])
     return out
+
+
+@functools.lru_cache(maxsize=None)
+def _row_writer(tree, shardings: tuple):
+    return jax.jit(insert_rows, donate_argnums=0,
+                   out_shardings=jax.tree.unflatten(tree, shardings))
+
+
+def write_row(global_cache, request_cache, row: int):
+    """``insert_rows`` as one compiled program with ``global_cache``
+    donated: the row is written in place, with no second copy of the
+    cache, and ``global_cache`` must not be used after the call.  The
+    row index is traced, so every slot shares the program; each leaf
+    comes back with the sharding it came in with (a leaf not committed
+    to a device stays uncommitted)."""
+    leaves, tree = jax.tree.flatten(global_cache)
+    shardings = tuple(a.sharding if a.committed else None for a in leaves)
+    return _row_writer(tree, shardings)(global_cache, request_cache,
+                                        np.int32(row))
 
 
 def extract_row(global_cache, row: int):
